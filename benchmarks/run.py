@@ -88,7 +88,9 @@ def bench_sharding() -> dict:
     import os
     import subprocess
 
-    env = dict(os.environ)
+    # the child is an emulated CPU mesh by design, so it never reaches
+    # for an accelerator this process may already hold
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     env.pop("XLA_FLAGS", None)
     out = subprocess.run([sys.executable, "-c", _SHARD_BENCH], env=env,
                          capture_output=True, text=True, timeout=1800)

@@ -100,7 +100,7 @@ def compress_params(params: Dict, spec: CompressionSpec = None, *,
             from repro.kernels import ops, tune
             w0 = acsr_mod.prune_topk(np.asarray(leaf[0]).T, spec.density)
             block_rows = tune.choose_block_rows(
-                w0, leaf_mode, spec.density, default=spec.block_rows,
+                w0, leaf_mode, spec.density,
                 interpret=ops.pallas_interpret())
         per = [sfc.compress(np.asarray(leaf[i]).T, mode=leaf_mode,
                             density=spec.density, k=spec.k,

@@ -19,15 +19,10 @@ Knobs:
 ``REPRO_AUTOTUNE``      "0"/"false" disables the kernel autotuner
 ``REPRO_TUNE_BLOCK_ROWS``  "1" enables encode-time block_rows search
 ``REPRO_BF16_PSUM``     "1" narrows TP matmul partial sums to bf16
-``REPRO_PALLAS_INTERPRET``  force Pallas interpret ("1") or native ("0");
-                        unset -> auto-detect (interpret off-TPU), which
-                        must stay lazy because the jax backend is not
-                        known at import time
 """
 from __future__ import annotations
 
 import os
-from typing import Optional
 
 KV_CACHE: str = os.environ.get("REPRO_KV_CACHE", "auto")
 KV_DTYPE: str = os.environ.get("REPRO_KV_DTYPE", "bf16")
@@ -35,7 +30,3 @@ KV_UPDATE: str = os.environ.get("REPRO_KV_UPDATE", "scatter")
 AUTOTUNE: bool = os.environ.get("REPRO_AUTOTUNE", "1") not in ("0", "false")
 TUNE_BLOCK_ROWS: bool = os.environ.get("REPRO_TUNE_BLOCK_ROWS") == "1"
 BF16_PSUM: bool = os.environ.get("REPRO_BF16_PSUM") == "1"
-#: raw override for Pallas interpret mode; None = auto-detect per backend
-PALLAS_INTERPRET: Optional[bool] = (
-    None if (_pi := os.environ.get("REPRO_PALLAS_INTERPRET")) is None
-    else _pi not in ("0", "false", "False"))

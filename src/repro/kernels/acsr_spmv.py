@@ -50,6 +50,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.util import apply_activation as _act
 from repro.kernels.util import cdiv as _cdiv
+from repro.kernels.util import interpret_mode
 
 
 # --------------------------------------------------------------- format
@@ -158,55 +159,70 @@ def block_encode_coded(dense: np.ndarray, centroids: np.ndarray,
 
 # --------------------------------------------------------------- kernel
 def _fused_spmv_kernel(vals_ref, cols_ref, nnz_ref, x_ref, *opt_refs,
-                       block_rows: int, bk: int, n_k_blocks: int,
-                       coded: bool, has_bias: bool,
-                       activation: Optional[str]):
+                       bk: int, n_k_blocks: int, coded: bool,
+                       has_bias: bool, activation: Optional[str]):
     """One grid step = the Fig. 3 pipeline for ``mb`` row blocks over one
-    K tile.  opt_refs order: [cents], [bias], out, acc(scratch)."""
+    K tile.  opt_refs order: [cents], [bias], out, acc, w/col scratch.
+
+    Every gather stays inside one vreg, the only gather the TPU lowers:
+    centroids are a ``[1, br]`` lane row indexed by code, and the
+    activation tile ``x [B, bk]`` is read as ``bk / br`` lane chunks of
+    ``[B, br]``, each gathered at the slot's in-chunk column and kept
+    where the column falls in that chunk."""
     refs = list(opt_refs)
     cents_ref = refs.pop(0) if coded else None
     bias_ref = refs.pop(0) if has_bias else None
-    o_ref, acc_ref = refs
+    o_ref, acc_ref, w_scr, col_scr = refs
     kb = pl.program_id(1)
 
     @pl.when(kb == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    vals = vals_ref[...]                                # [mb, rmax, br]
-    if coded:
-        vals = jnp.take(cents_ref[0], vals.astype(jnp.int32), axis=0)
-    mb, rmax, br = vals.shape
-    cols = cols_ref[...].astype(jnp.int32)              # [mb, rmax, br]
-    # precomputed segment structure: slot >= row_nnz is padding
-    slot = jax.lax.broadcasted_iota(jnp.int32, (mb, rmax, br), 1)
-    live = slot < nnz_ref[...][:, None, :]              # [mb, rmax, br]
-    # K-tiled activation broadcast: gather from the resident [bk, B] slice,
-    # masking entries whose column lives in another K tile
-    local = cols - kb * bk
-    in_tile = live & (local >= 0) & (local < bk)
-    x = x_ref[...]                                      # [bk, B]
-    gathered = jnp.take(x, jnp.clip(local, 0, bk - 1).reshape(-1),
-                        axis=0).reshape(mb, rmax, br, -1)
-    prod = jnp.where(in_tile, vals.astype(jnp.float32), 0.0)[..., None] \
-        * gathered.astype(jnp.float32)
-    # soft reduction: the segment one-hot is static under the slot
-    # schedule (kron(I_br, 1_rmax)) -> plain slot-axis sum
-    acc_ref[...] += prod.sum(axis=1)                    # [mb, br, B]
+    mb, rmax, br = vals_ref.shape
+    bsz = x_ref.shape[0]
+    x = x_ref[...].astype(jnp.float32)                  # [B, bk]
+    chunks = [x[:, c * br:(c + 1) * br] for c in range(bk // br)]
+    for m in range(mb):                                  # static unroll
+        vals = vals_ref[m]                               # [rmax, br]
+        if coded:
+            vals = jnp.take_along_axis(
+                jnp.broadcast_to(cents_ref[...], (rmax, br)),
+                vals.astype(jnp.int32), axis=1)
+        w_scr[...] = vals.astype(jnp.float32)
+        col_scr[...] = cols_ref[m].astype(jnp.int32) - kb * bk
+        nnz = nnz_ref[m]                                 # [1, br]
+
+        def slot(s, acc, nnz=nnz):
+            # slot s of every row in the block: its column (local to this
+            # K tile) and value; slot >= row_nnz is padding
+            local = col_scr[pl.ds(s, 1), :]              # [1, br]
+            live = (s < nnz) & (local >= 0) & (local < bk)
+            w = jnp.where(live, w_scr[pl.ds(s, 1), :], 0.0)
+            lc = jnp.clip(local, 0, bk - 1)
+            idx = jnp.broadcast_to(jax.lax.rem(lc, br), (bsz, br))
+            chunk = jax.lax.div(lc, br)
+            g = jnp.zeros((bsz, br), jnp.float32)
+            for c, xc in enumerate(chunks):
+                g = jnp.where(chunk == c,
+                              jnp.take_along_axis(xc, idx, axis=1), g)
+            return acc + w * g
+        acc_ref[m] += jax.lax.fori_loop(
+            0, rmax, slot, jnp.zeros((bsz, br), jnp.float32))
 
     @pl.when(kb == n_k_blocks - 1)
     def _done():
-        y = acc_ref[...]
+        y = acc_ref[...]                                 # [mb, B, br]
         if has_bias:
-            y = y + bias_ref[...][..., None]            # [mb, br, 1]
+            y = y + bias_ref[...]                        # [mb, 1, br]
         o_ref[...] = _act(activation, y)
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "block_rows", "mb", "bk", "activation", "interpret"))
+    "mb", "bk", "activation", "interpret"))
 def _spmv_call(values, col_idx, row_nnz, x2d, centroids, bias, *,
-               block_rows: int, mb: int, bk: int,
-               activation: Optional[str], interpret: bool):
+               mb: int, bk: int, activation: Optional[str],
+               interpret: bool):
     nblocks, rmax, br = values.shape
     k, bsz = x2d.shape
     coded = centroids is not None
@@ -218,41 +234,49 @@ def _spmv_call(values, col_idx, row_nnz, x2d, centroids, bias, *,
         values = jnp.pad(values, ((0, pad_b), (0, 0), (0, 0)))
         col_idx = jnp.pad(col_idx, ((0, pad_b), (0, 0), (0, 0)))
         row_nnz = jnp.pad(row_nnz, ((0, pad_b), (0, 0)))
-    # pad K to a multiple of bk (zero activations never contribute)
+    # pad K to a multiple of bk (zero activations never contribute); the
+    # activations ride transposed, the batch on whole sublane tiles
     n_k = _cdiv(k, bk)
-    if n_k * bk != k:
-        x2d = jnp.pad(x2d, ((0, n_k * bk - k), (0, 0)))
+    bp = _cdiv(bsz, 8) * 8
+    x_t = jnp.pad(x2d.T, ((0, bp - bsz), (0, n_k * bk - k)))
     grid = (nsuper, n_k)
     in_specs = [
         pl.BlockSpec((mb, rmax, br), lambda i, kb: (i, 0, 0)),
         pl.BlockSpec((mb, rmax, br), lambda i, kb: (i, 0, 0)),
-        pl.BlockSpec((mb, br), lambda i, kb: (i, 0)),
-        pl.BlockSpec((bk, bsz), lambda i, kb: (kb, 0)),
+        pl.BlockSpec((mb, 1, br), lambda i, kb: (i, 0, 0)),
+        pl.BlockSpec((bp, bk), lambda i, kb: (0, kb)),
     ]
-    args = [values, col_idx, row_nnz, x2d]
+    args = [values, col_idx, row_nnz.reshape(-1, 1, br), x_t]
     if coded:
-        cents2d = centroids.reshape(1, -1)
-        in_specs.append(pl.BlockSpec((1, cents2d.shape[1]),
-                                     lambda i, kb: (0, 0)))
+        n_cents = centroids.shape[0]
+        if n_cents > br:
+            raise ValueError(f"{n_cents} centroids do not fit one "
+                             f"{br}-lane row")
+        cents2d = jnp.pad(centroids.astype(jnp.float32),
+                          (0, br - n_cents)).reshape(1, br)
+        in_specs.append(pl.BlockSpec((1, br), lambda i, kb: (0, 0)))
         args.append(cents2d)
     if has_bias:
-        bias2d = jnp.pad(bias.astype(jnp.float32),
+        bias3d = jnp.pad(bias.astype(jnp.float32),
                          (0, (nblocks + pad_b) * br - bias.shape[0])
-                         ).reshape(-1, br)
-        in_specs.append(pl.BlockSpec((mb, br), lambda i, kb: (i, 0)))
-        args.append(bias2d)
+                         ).reshape(-1, 1, br)
+        in_specs.append(pl.BlockSpec((mb, 1, br), lambda i, kb: (i, 0, 0)))
+        args.append(bias3d)
     kern = functools.partial(
-        _fused_spmv_kernel, block_rows=br, bk=bk, n_k_blocks=n_k,
-        coded=coded, has_bias=has_bias, activation=activation)
-    return pl.pallas_call(
+        _fused_spmv_kernel, bk=bk, n_k_blocks=n_k, coded=coded,
+        has_bias=has_bias, activation=activation)
+    out = pl.pallas_call(
         kern,
         grid=grid,
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((mb, br, bsz), lambda i, kb: (i, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((nsuper * mb, br, bsz), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((mb, br, bsz), jnp.float32)],
+        out_specs=pl.BlockSpec((mb, bp, br), lambda i, kb: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((nsuper * mb, bp, br), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((mb, bp, br), jnp.float32),
+                        pltpu.VMEM((rmax, br), jnp.float32),
+                        pltpu.VMEM((rmax, br), jnp.int32)],
         interpret=interpret,
     )(*args)
+    return out[:, :bsz].transpose(0, 2, 1).reshape(-1, bsz)  # [rows, B]
 
 
 def default_tiles(nblocks: int, k: int) -> Tuple[int, int]:
@@ -267,20 +291,23 @@ def acsr_spmv(b: BlockedACSR, x: jnp.ndarray, *,
               bias: Optional[jnp.ndarray] = None,
               activation: Optional[str] = None,
               mb: Optional[int] = None, bk: Optional[int] = None,
-              interpret: bool = True) -> jnp.ndarray:
+              interpret: Optional[bool] = None) -> jnp.ndarray:
     """Sparse (optionally coded) fused pipeline: act(W @ x + bias).
 
     x: [K] or [K, B]; bias: [n_rows] broadcast over B.  Returns
     [n_rows] / [n_rows, B] f32.  ``mb``/``bk`` select the fused tile
-    shape (see kernels.tune for the autotuner that picks them).
+    shape (see kernels.tune for the autotuner that picks them); ``bk``
+    rounds up to a whole number of ``block_rows``-lane chunks.
+    ``interpret=None`` lowers natively on a TPU and interprets elsewhere.
     """
     squeeze = x.ndim == 1
     x2d = x[:, None] if squeeze else x
     d_mb, d_bk = default_tiles(b.nblocks, x2d.shape[0])
     mb = d_mb if mb is None else min(mb, b.nblocks)
     bk = d_bk if bk is None else min(bk, x2d.shape[0])
+    bk = _cdiv(bk, b.block_rows) * b.block_rows
     out = _spmv_call(b.values, b.col_idx, b.row_nnz, x2d, b.centroids,
-                     bias, block_rows=b.block_rows, mb=mb, bk=bk,
-                     activation=activation, interpret=interpret)
-    out = out.reshape(-1, out.shape[-1])[: b.shape[0]]
+                     bias, mb=mb, bk=bk, activation=activation,
+                     interpret=interpret_mode(interpret))
+    out = out[: b.shape[0]]
     return out[:, 0] if squeeze else out

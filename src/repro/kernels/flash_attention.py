@@ -21,6 +21,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.util import interpret_mode
+
 NEG_INF = -1e30
 
 
@@ -83,7 +85,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
     jax.jit, static_argnames=("causal", "window", "softcap", "scale",
                               "bq", "bk", "interpret"))
 def flash_attention_fwd(q, k, v, *, causal=True, window=None, softcap=None,
-                        scale=None, bq=128, bk=128, interpret=True):
+                        scale=None, bq=128, bk=128, interpret=None):
     """q [B,H,T,D], k/v [B,Hkv,T,D] -> (o [B,H,T,D] f32, lse [B,H,T,1])."""
     b, h, tq, d = q.shape
     _, hkv, tk, _ = k.shape
@@ -119,7 +121,7 @@ def flash_attention_fwd(q, k, v, *, causal=True, window=None, softcap=None,
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, d), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(q, k, v)
     return o, lse
 
@@ -206,7 +208,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
                               "bq", "bk", "interpret"))
 def flash_attention_bwd(q, k, v, o, lse, do, *, causal=True, window=None,
                         softcap=None, scale=None, bq=128, bk=128,
-                        interpret=True):
+                        interpret=None):
     b, h, tq, d = q.shape
     _, hkv, tk, _ = k.shape
     group = h // hkv
@@ -235,7 +237,7 @@ def flash_attention_bwd(q, k, v, o, lse, do, *, causal=True, window=None,
                                lambda bi, hi, iq, jk: (bi, hi, iq, 0)),
         out_shape=jax.ShapeDtypeStruct((b, h, tq, d), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(q, k, v, do, lse, delta)
 
     dk, dv = pl.pallas_call(
@@ -267,6 +269,6 @@ def flash_attention_bwd(q, k, v, o, lse, do, *, causal=True, window=None,
         ],
         scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
                         pltpu.VMEM((bk, d), jnp.float32)],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(q, k, v, do, lse, delta)
     return dq, dk, dv

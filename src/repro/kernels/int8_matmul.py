@@ -22,6 +22,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.util import apply_activation as _act
 from repro.kernels.util import cdiv as _cdiv
+from repro.kernels.util import interpret_mode
 
 
 def _int8_kernel(x_ref, q_ref, scale_ref, *opt_refs, n_k_blocks: int,
@@ -58,12 +59,13 @@ def int8_matmul(x: jnp.ndarray, q: jnp.ndarray, scale: jnp.ndarray, *,
                 bias: Optional[jnp.ndarray] = None,
                 activation: Optional[str] = None,
                 bm: int = 8, bn: int = 128, bk: int = 512,
-                interpret: bool = True) -> jnp.ndarray:
+                interpret: Optional[bool] = None) -> jnp.ndarray:
     """act(x [B,K] @ (q [N,K] * scale [N,1|1,1]).T + bias [N]) -> [B,N] f32.
 
     BlockSpecs: x tiles [bm,bk] f32, weight tiles [bn,bk] int8 (1 byte/
     weight of VMEM), scale/bias replicated per n tile.  All dims are
     padded to the tile grid and the output sliced back.
+    ``interpret=None`` lowers natively on a TPU only.
     """
     b, k = x.shape
     n, k2 = q.shape
@@ -98,6 +100,6 @@ def int8_matmul(x: jnp.ndarray, q: jnp.ndarray, scale: jnp.ndarray, *,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kb: (i, j)),
         out_shape=jax.ShapeDtypeStruct((bp, np_), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(*args)
     return out[:b, :n]

@@ -18,11 +18,14 @@ path; this kernel is the inference engine.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.util import interpret_mode
 
 
 def _rwkv6_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, s_scr, *,
@@ -49,7 +52,8 @@ def _rwkv6_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, s_scr, *,
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def rwkv6_fwd(r, k, v, w, u, *, chunk: int = 64, interpret: bool = True):
+def rwkv6_fwd(r, k, v, w, u, *, chunk: int = 64,
+              interpret: Optional[bool] = None):
     """r,k,w [B,H,T,Dk], v [B,H,T,Dv], u [H,Dk] -> o [B,H,T,Dv] f32."""
     b, h, t, dk = r.shape
     dv = v.shape[-1]
@@ -70,6 +74,6 @@ def rwkv6_fwd(r, k, v, w, u, *, chunk: int = 64, interpret: bool = True):
         out_specs=pl.BlockSpec((1, chunk, dv), lambda i, tb: (i, tb, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, t, dv), jnp.float32),
         scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32)],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(flat(r), flat(k), flat(v), flat(w), u)
     return o.reshape(b, h, t, dv)

@@ -1,10 +1,12 @@
 """Public jit'd kernel API — dispatch between Pallas kernels and jnp refs.
 
-On this (CPU) container Pallas runs in interpret mode; on TPU set
-``REPRO_PALLAS_INTERPRET=0`` (or rely on the backend auto-detection) to lower
-the kernels natively.  Training paths that need autodiff either use a
-custom_vjp pairing the fwd/bwd kernels (attention) or a differentiable
-lax.scan formulation (recurrences).
+Pallas kernels lower natively when the jax backend is a TPU and run in
+interpret mode on any other backend (:func:`pallas_interpret`); a caller
+that needs one or the other passes ``interpret=`` explicitly, as the CPU
+tests (``True``) and the TPU compile tests (``False``) do.  Training
+paths that need autodiff either use a custom_vjp pairing the fwd/bwd
+kernels (attention) or a differentiable lax.scan formulation
+(recurrences).
 """
 from __future__ import annotations
 
@@ -14,7 +16,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.api import env
 from repro.kernels import ref as _ref
 from repro.kernels import flash_attention as _fa
 from repro.kernels import int8_matmul as _i8
@@ -22,12 +23,7 @@ from repro.kernels import linear_scan as _ls
 from repro.kernels import lut_matmul as _lm
 from repro.kernels import acsr_spmv as _sp
 from repro.kernels import tune as _tune
-
-
-def pallas_interpret() -> bool:
-    if env.PALLAS_INTERPRET is not None:
-        return env.PALLAS_INTERPRET
-    return jax.default_backend() != "tpu"
+from repro.kernels.util import pallas_interpret
 
 
 # ---------------------------------------------------------------- attention

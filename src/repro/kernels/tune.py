@@ -10,7 +10,7 @@ back at trace time by the `ops` dispatchers:
                                  a fused XLA matmul on interpret-mode hosts;
                                  the tuner measures instead of guessing)
   block_rows    — encode-time row-block height (searched at compress time
-                  when REPRO_TUNE_BLOCK_ROWS=1; re-encodes per candidate)
+                  when REPRO_TUNE_BLOCK_ROWS=1; one encoding per candidate)
 
 `Engine.session()` calls :func:`tune_params` before compiling the decode
 step, so every unique CompressedFC geometry is tuned eagerly (outside any
@@ -22,6 +22,11 @@ The cache is process-global and keyed on everything that changes the
 winner: kind, geometry, batch width, and interpret vs native lowering.
 Tiles are read at trace time — re-tuning after a step has been compiled
 does not retroactively change that step.
+
+A candidate that fails to compile or run is kept with its error on the
+winner (``failed``, shown by :func:`snapshot`); a key whose Pallas
+candidates all fail raises :class:`TuneError` instead of quietly serving
+the XLA reference.
 """
 from __future__ import annotations
 
@@ -35,20 +40,32 @@ from repro.api import env
 Key = Tuple
 
 
+class TuneError(RuntimeError):
+    """Every Pallas candidate of a key failed to compile or run."""
+
+
 @dataclasses.dataclass(frozen=True)
 class KernelChoice:
     """One point in a kernel's implementation/tile space."""
     impl: str = "pallas"
     tiles: Tuple[Tuple[str, int], ...] = ()
     us: float = float("nan")          # measured microseconds (best run)
+    #: (candidate label, error) of every candidate that failed to run
+    failed: Tuple[Tuple[str, str], ...] = ()
 
     def tile(self, name: str, default: Optional[int] = None) -> Optional[int]:
         return dict(self.tiles).get(name, default)
+
+    @property
+    def label(self) -> str:
+        return "/".join([self.impl] + [f"{k}={v}" for k, v in self.tiles])
 
     def to_json(self) -> dict:
         d = {"impl": self.impl, **dict(self.tiles)}
         if np.isfinite(self.us):
             d["us"] = round(self.us, 1)
+        if self.failed:
+            d["failed"] = dict(self.failed)
         return d
 
 
@@ -149,13 +166,16 @@ def paged_candidates(npp: int) -> List[KernelChoice]:
     return cands
 
 
-def paged_chunk_candidates(npp: int, chunk: int) -> List[KernelChoice]:
+def paged_chunk_candidates(npp: int, chunk: int,
+                           group: int) -> List[KernelChoice]:
     """Chunked-prefill space: XLA gather reference vs the Pallas chunk
-    kernel over (pb page blocks) x (qt query tiles dividing the chunk)."""
-    from repro.kvstore.paged_attention import npp_bucket
+    kernel over (pb page blocks) x (qt query tiles the kernel can run
+    natively at this GQA group size)."""
+    from repro.kvstore.paged_attention import legal_query_tile, npp_bucket
     cands = [KernelChoice("xla")]
     pbs = sorted({min(p, npp_bucket(npp)) for p in (1, 2, 4)})
-    qts = sorted({q for q in (1, 2, 4, chunk) if chunk % q == 0})
+    qts = sorted({q for q in (1, 2, 4, 8, 16, chunk)
+                  if legal_query_tile(q, chunk, group)})
     for pb in pbs:
         for qt in qts:
             cands.append(KernelChoice("pallas", (("pb", pb), ("qt", qt))))
@@ -170,23 +190,31 @@ def autotune(key: Key, candidates: Sequence[KernelChoice],
     back-to-back calls, best sample) and cache the winner under ``key``.
     Sub-ms kernels need the inner loop — single-call samples are noise on
     a busy host and a wrong pick taxes every decode step afterwards.
-    Candidates that fail to compile or run are skipped; an already-cached
-    key returns immediately."""
+    A candidate that fails to compile or run is recorded with its error
+    on the winner; if no Pallas candidate runs, :class:`TuneError` is
+    raised and nothing is cached.  An already-cached key returns
+    immediately."""
     from repro.obs import timeit
     cached = get(key)
     if cached is not None:
         return cached
-    best: Optional[KernelChoice] = None
+    timed: List[KernelChoice] = []
+    failed: List[Tuple[str, str]] = []
     for cand in candidates:
         try:
             t_best = timeit(runner, cand, reps=reps, inner=inner)
-        except Exception:
+        except Exception as e:  # a refused candidate is data, kept below
+            msg = str(e).strip().splitlines()
+            failed.append((cand.label, f"{type(e).__name__}: "
+                           f"{msg[0][:300] if msg else ''}"))
             continue
-        timed = dataclasses.replace(cand, us=t_best * 1e6)
-        if best is None or timed.us < best.us:
-            best = timed
-    if best is None:  # nothing ran — record a no-op marker so we don't loop
-        best = KernelChoice("pallas")
+        timed.append(dataclasses.replace(cand, us=t_best * 1e6))
+    if not any(c.impl == "pallas" for c in timed):
+        raise TuneError(
+            f"no Pallas candidate of {'/'.join(map(str, key))} ran: "
+            + "; ".join(f"{lab}: {err}" for lab, err in failed))
+    best = dataclasses.replace(min(timed, key=lambda c: c.us),
+                               failed=tuple(failed))
     record(key, best)
     return best
 
@@ -274,6 +302,29 @@ def tune_layer(layer, batch: int, interpret: bool) -> Optional[KernelChoice]:
     return None
 
 
+def _filled_pool(cfg, batch: int, max_len: int, page_size: int,
+                 kv_dtype: str):
+    """A synthetic pool in which every table slot owns a page and every
+    position is written (one jitted chunk write): the full-occupancy
+    gather, the steady-state cost of a long sequence."""
+    import jax
+    import jax.numpy as jnp
+    from repro import kvstore as kvsto
+
+    hkv, dh = cfg.n_kv, cfg.head_dim
+    npp = -(-max_len // page_size)
+    pool = kvsto.init_pool(1 + batch * npp, hkv, page_size, dh,
+                           kv_dtype=kv_dtype)
+    table = jnp.asarray(
+        1 + np.arange(batch * npp).reshape(batch, npp), jnp.int32)
+    rng = np.random.default_rng(0)
+    k, v = (jnp.asarray(rng.normal(size=(batch, hkv, max_len, dh)),
+                        jnp.float32) for _ in range(2))
+    pos = jnp.broadcast_to(jnp.arange(max_len, dtype=jnp.int32),
+                           (batch, max_len))
+    return jax.jit(kvsto.update_chunk)(pool, table, k, v, pos), table
+
+
 def tune_paged(cfg, batch: int, max_len: int, page_size: int,
                kv_dtype: str, interpret: bool) -> Optional[KernelChoice]:
     """Search the paged-attention impl/tile space for one serving
@@ -292,29 +343,17 @@ def tune_paged(cfg, batch: int, max_len: int, page_size: int,
     if get(key) is not None:
         return get(key)
     rng = np.random.default_rng(0)
-    pool = kvsto.init_pool(1 + batch * npp, hkv, page_size, dh,
-                           kv_dtype=kv_dtype)
-    # every table slot owns a page and every slot is written: tune on the
-    # full-occupancy gather, the steady-state cost of a long sequence
-    table = jnp.asarray(
-        1 + np.arange(batch * npp).reshape(batch, npp), jnp.int32)
-    for t in range(max_len):
-        pool = kvsto.update(
-            pool, table,
-            jnp.asarray(rng.normal(size=(batch, hkv, dh)), jnp.float32),
-            jnp.asarray(rng.normal(size=(batch, hkv, dh)), jnp.float32),
-            jnp.full((batch,), t, jnp.int32))
+    pool, table = _filled_pool(cfg, batch, max_len, page_size, kv_dtype)
     q = jnp.asarray(rng.normal(size=(batch, cfg.n_heads, dh)), jnp.float32)
     cur = jnp.full((batch,), max_len - 1, jnp.int32)
     win = jnp.int32(-1)
     # jit the XLA candidate — inside a decode step it runs XLA-fused
-    xla_run = jax.jit(lambda qq, cc, ww: kvsto.paged_attention_xla(
-        qq, pool, table, cc, ww, scale=cfg.attn_scale,
-        cap=cfg.attn_softcap))
+    xla_run = jax.jit(lambda qq, pp, tt, cc, ww: kvsto.paged_attention_xla(
+        qq, pp, tt, cc, ww, scale=cfg.attn_scale, cap=cfg.attn_softcap))
 
     def run(c):
         if c.impl == "xla":
-            return xla_run(q, cur, win)
+            return xla_run(q, pool, table, cur, win)
         return kvsto.paged_attention_pallas(
             q, pool, table, cur, win, scale=cfg.attn_scale,
             cap=cfg.attn_softcap, pb=c.tile("pb", 2), interpret=interpret)
@@ -343,16 +382,7 @@ def tune_paged_chunk(cfg, batch: int, max_len: int, page_size: int,
     if get(key) is not None:
         return get(key)
     rng = np.random.default_rng(0)
-    pool = kvsto.init_pool(1 + batch * npp, hkv, page_size, dh,
-                           kv_dtype=kv_dtype)
-    table = jnp.asarray(
-        1 + np.arange(batch * npp).reshape(batch, npp), jnp.int32)
-    for t in range(max_len):
-        pool = kvsto.update(
-            pool, table,
-            jnp.asarray(rng.normal(size=(batch, hkv, dh)), jnp.float32),
-            jnp.asarray(rng.normal(size=(batch, hkv, dh)), jnp.float32),
-            jnp.full((batch,), t, jnp.int32))
+    pool, table = _filled_pool(cfg, batch, max_len, page_size, kv_dtype)
     q = jnp.asarray(rng.normal(size=(batch, cfg.n_heads, chunk, dh)),
                     jnp.float32)
     # query the trailing chunk of the sequence (the worst-case mask span)
@@ -360,18 +390,18 @@ def tune_paged_chunk(cfg, batch: int, max_len: int, page_size: int,
         jnp.arange(max_len - chunk, max_len, dtype=jnp.int32)[None, :],
         (batch, chunk))
     win = jnp.int32(-1)
-    xla_run = jax.jit(lambda qq, pp, ww: kvsto.paged_attention_xla_chunk(
-        qq, pool, table, pp, ww, scale=cfg.attn_scale,
-        cap=cfg.attn_softcap))
+    xla_run = jax.jit(
+        lambda qq, pp, tt, qp, ww: kvsto.paged_attention_xla_chunk(
+            qq, pp, tt, qp, ww, scale=cfg.attn_scale, cap=cfg.attn_softcap))
 
     def run(c):
         if c.impl == "xla":
-            return xla_run(q, q_pos, win)
+            return xla_run(q, pool, table, q_pos, win)
         return kvsto.paged_attention_pallas_chunk(
             q, pool, table, q_pos, win, scale=cfg.attn_scale,
             cap=cfg.attn_softcap, pb=c.tile("pb", 2),
             qt=c.tile("qt", chunk), interpret=interpret)
-    return autotune(key, paged_chunk_candidates(npp, chunk), run)
+    return autotune(key, paged_chunk_candidates(npp, chunk, group), run)
 
 
 def tune_params(params, batch: int, interpret: bool) -> int:
@@ -398,44 +428,46 @@ def tune_params(params, batch: int, interpret: bool) -> int:
 
 
 # --------------------------------------------------- encode-time block_rows
-_BLOCK_ROWS_CACHE: Dict[Tuple, int] = {}
+def block_rows_key(shape: Tuple[int, int], mode: str, density: float,
+                   interpret: bool) -> Key:
+    return ("block_rows", mode, *shape, density,
+            "interp" if interpret else "tpu")
 
 
 def choose_block_rows(w: np.ndarray, mode: str, density: float,
-                      default: int = 128, batch: int = 2,
+                      batch: int = 2,
                       candidates: Sequence[int] = (64, 128, 256),
-                      interpret: bool = True) -> int:
-    """Encode-time tile search over the row-block height (re-encodes the
-    pruned matrix per candidate and times the fused kernel).  Cached by
-    (shape, mode); only consulted when REPRO_TUNE_BLOCK_ROWS=1 since
-    re-encoding per candidate is much slower than the (mb, bk) search."""
+                      interpret: Optional[bool] = None) -> int:
+    """Encode-time tile search over the row-block height: encode the
+    pruned matrix once per candidate and time the fused kernel on each.
+    Cached by (shape, mode, density, lowering); only consulted when
+    REPRO_TUNE_BLOCK_ROWS=1 since one encoding per candidate is much
+    slower than the (mb, bk) search."""
     import jax.numpy as jnp
     from repro.kernels import acsr_spmv as sp
-    from repro.obs import timeit
 
-    key = (w.shape, mode, density)
-    if key in _BLOCK_ROWS_CACHE:
-        return _BLOCK_ROWS_CACHE[key]
-    rng = np.random.default_rng(0)
-    x = jnp.asarray(rng.normal(size=(w.shape[1], batch)).astype(np.float32))
-    best, best_t = default, float("inf")
-    for br in candidates:
-        try:
-            if mode == "aida":
-                # time the coded kernel the real decode will run
-                nz = w[w != 0]
-                cents = np.concatenate(
-                    [[0.0], np.quantile(nz, np.linspace(0.02, 0.98, 15))]
-                ).astype(np.float32) if nz.size else np.zeros(16, np.float32)
-                blocked = sp.block_encode_coded(w, cents, block_rows=br)
-            else:
-                blocked = sp.block_encode(w, block_rows=br)
-            # best-of-3 samples of 3 calls (noise floor on a busy host)
-            dt = timeit(sp.acsr_spmv, blocked, x, interpret=interpret,
-                        reps=3, inner=3)
-        except Exception:
-            continue
-        if dt < best_t:
-            best, best_t = br, dt
-    _BLOCK_ROWS_CACHE[key] = best
-    return best
+    from repro.kernels.util import interpret_mode
+    interpret = interpret_mode(interpret)
+    key = block_rows_key(w.shape, mode, density, interpret)
+    if get(key) is None:
+        rng = np.random.default_rng(0)
+        x = jnp.asarray(rng.normal(size=(w.shape[1], batch))
+                        .astype(np.float32))
+        if mode == "aida":
+            # time the coded kernel the real decode will run
+            nz = w[w != 0]
+            cents = np.concatenate(
+                [[0.0], np.quantile(nz, np.linspace(0.02, 0.98, 15))]
+            ).astype(np.float32) if nz.size else np.zeros(16, np.float32)
+            blocked = {br: sp.block_encode_coded(w, cents, block_rows=br)
+                       for br in candidates}
+        else:
+            blocked = {br: sp.block_encode(w, block_rows=br)
+                       for br in candidates}
+
+        def run(c):
+            return sp.acsr_spmv(blocked[c.tile("block_rows")], x,
+                                interpret=interpret)
+        autotune(key, [KernelChoice("pallas", (("block_rows", br),))
+                       for br in candidates], run)
+    return get(key).tile("block_rows")

@@ -6,6 +6,18 @@ from typing import Optional
 import jax
 
 
+def pallas_interpret() -> bool:
+    """How Pallas kernels lower in this process: natively on a TPU
+    backend, through the interpreter everywhere else."""
+    return jax.default_backend() != "tpu"
+
+
+def interpret_mode(interpret: Optional[bool]) -> bool:
+    """A kernel entry point's ``interpret`` argument; None follows the
+    backend (:func:`pallas_interpret`)."""
+    return pallas_interpret() if interpret is None else interpret
+
+
 def cdiv(a: int, b: int) -> int:
     return (a + b - 1) // b
 

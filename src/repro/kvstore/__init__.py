@@ -17,14 +17,14 @@ from repro.kvstore.paged_attention import (npp_bucket, paged_attention,
                                            resolve_paged,
                                            resolve_paged_chunk)
 from repro.kvstore.pool import (GARBAGE_PAGE, NO_PAGE, PagedKV,
-                                attention_mask, chunk_attention_mask,
+                                chunk_attention_mask,
                                 copy_pages, dense_kv_bytes_per_token,
                                 gather_kv, init_pool, init_table,
                                 kv_bytes_per_token, update, update_chunk)
 
 __all__ = [
     "GARBAGE_PAGE", "NO_PAGE", "OutOfPages", "PageAllocator", "PagedKV",
-    "attention_mask", "chunk_attention_mask", "copy_pages",
+    "chunk_attention_mask", "copy_pages",
     "dense_kv_bytes_per_token",
     "gather_kv", "init_pool", "init_table", "kv_bytes_per_token",
     "npp_bucket", "paged_attention", "paged_attention_chunk",

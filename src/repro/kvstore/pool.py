@@ -280,28 +280,15 @@ def gather_kv(pool: PagedKV, table: jnp.ndarray
     return k, v
 
 
-def attention_mask(table: jnp.ndarray, cur_pos: jnp.ndarray,
-                   window: jnp.ndarray, page_size: int) -> jnp.ndarray:
-    """[B, npp*ps] bool: positions a query at cur_pos may attend to.
-    Table index is absolute position; window < 0 means full causal."""
-    b, npp = table.shape
-    pos = jnp.arange(npp * page_size)[None, :]            # [1, npp*ps]
-    alloc = jnp.repeat(table >= 0, page_size, axis=1)     # [B, npp*ps]
-    ok = alloc & (pos <= cur_pos[:, None])
-    win_lo = jnp.where(window < 0, jnp.int32(-1),
-                       cur_pos[:, None] - window)
-    return ok & (pos > win_lo)
-
-
 def chunk_attention_mask(table: jnp.ndarray, q_pos: jnp.ndarray,
                          window: jnp.ndarray,
                          page_size: int) -> jnp.ndarray:
-    """[B, C, npp*ps] bool: positions each of C chunk queries (at absolute
-    positions ``q_pos`` [B, C]) may attend to — the multi-query
-    generalization of :func:`attention_mask` for the chunked-prefill
-    step.  Every key position <= a query's position has been written by
-    the time the chunk attends (writes happen first, in position order),
-    so plain causality over table-index positions is sufficient."""
+    """[B, C, npp*ps] bool: positions each of C queries (at absolute
+    positions ``q_pos`` [B, C]) may attend to; table index is absolute
+    position, and window < 0 means full causal.  Every key position <= a
+    query's position has been written by the time the query attends
+    (writes happen first, in position order), so plain causality over
+    table-index positions is sufficient."""
     b, npp = table.shape
     pos = jnp.arange(npp * page_size)[None, None, :]      # [1, 1, npp*ps]
     alloc = jnp.repeat(table >= 0, page_size,

@@ -5,13 +5,22 @@ from __future__ import annotations
 from typing import Optional, Tuple
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
+    """``jax.make_mesh`` with Auto axes: the repo shards through
+    ``NamedSharding`` plus ``shard_map`` and lets the partitioner place
+    everything else, which Explicit axes (the jax default) refuse."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """Single pod: 256 chips (16 data × 16 model).  Multi-pod: 2 × 256."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(n_model: Optional[int] = None,
@@ -48,7 +57,7 @@ def make_host_mesh(n_model: Optional[int] = None,
             f"{n_data * n_model} devices but jax.device_count()={n}; "
             "set XLA_FLAGS=--xla_force_host_platform_device_count "
             "accordingly BEFORE importing jax")
-    return jax.make_mesh((n_data, n_model), ("data", "model"))
+    return make_mesh((n_data, n_model), ("data", "model"))
 
 
 def make_role_meshes(n_prefill: int, n_decode: int):
@@ -82,7 +91,7 @@ def make_role_meshes(n_prefill: int, n_decode: int):
 
 def make_pp_mesh():
     """Optional pipeline-parallel mesh (4 stages × 8 data × 8 model)."""
-    return jax.make_mesh((4, 8, 8), ("pipe", "data", "model"))
+    return make_mesh((4, 8, 8), ("pipe", "data", "model"))
 
 
 def mesh_shape_dict(mesh) -> dict:

@@ -82,6 +82,8 @@ from repro.sched.workload import PRESETS
 
 
 def main():
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--compress", default=None,

@@ -61,7 +61,7 @@ def main():
     if args.local_mesh or (not distributed
                            and jax.device_count() < 256):
         n = jax.device_count()
-        mesh = jax.make_mesh((1, n), ("data", "model"))
+        mesh = mesh_lib.make_mesh((1, n), ("data", "model"))
         print(f"[launch] local mesh 1x{n} (smoke mode)")
     else:
         mesh = mesh_lib.make_production_mesh(multi_pod=args.multi_pod)

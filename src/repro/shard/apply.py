@@ -39,7 +39,6 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core import sparse_fc as sfc
@@ -109,10 +108,10 @@ def apply_fc_sharded(plan, layer: sfc.CompressedFC, x: jnp.ndarray,
                              preferred_element_type=jnp.float32)
             return jax.lax.psum(acc, ax)
 
-        acc = shard_map(local_psum, mesh=plan.mesh,
-                        in_specs=(P(None, ax), P(None, ax)),
-                        out_specs=P(None, None),
-                        check_rep=False)(layer.qt.q, x)
+        acc = jax.shard_map(local_psum, mesh=plan.mesh,
+                            in_specs=(P(None, ax), P(None, ax)),
+                            out_specs=P(None, None),
+                            check_vma=False)(layer.qt.q, x)
         # slice padded rows off BEFORE the epilogue: bias carries the
         # true n_out, the padded q/scale rows are inert
         y = acc[:, :n_out] * layer.qt.scale.reshape(1, -1)[:, :n_out]
@@ -129,18 +128,18 @@ def apply_fc_sharded(plan, layer: sfc.CompressedFC, x: jnp.ndarray,
         def local(lay, xx):
             return sfc.apply_fc(_local_layer(lay), xx,
                                 activation=activation)
-        y = shard_map(local, mesh=plan.mesh,
-                      in_specs=(_row_specs(layer, ax), P(None, None)),
-                      out_specs=P(None, ax), check_rep=False)(layer, x)
+        y = jax.shard_map(local, mesh=plan.mesh,
+                          in_specs=(_row_specs(layer, ax), P(None, None)),
+                          out_specs=P(None, ax), check_vma=False)(layer, x)
     else:
         def local(lay, xx, bb):
             return sfc.apply_fc(_local_layer(lay), xx, bias=bb,
                                 activation=activation)
-        y = shard_map(local, mesh=plan.mesh,
-                      in_specs=(_row_specs(layer, ax), P(None, None),
-                                P(ax)),
-                      out_specs=P(None, ax),
-                      check_rep=False)(layer, x, bias_p)
+        y = jax.shard_map(local, mesh=plan.mesh,
+                          in_specs=(_row_specs(layer, ax), P(None, None),
+                                    P(ax)),
+                          out_specs=P(None, ax),
+                          check_vma=False)(layer, x, bias_p)
     return y[:, :n_out]
 
 
@@ -188,11 +187,11 @@ def paged_attention_sharded(plan, q: jnp.ndarray, pool, table: jnp.ndarray,
                                   cap=cap, impl=impl, pb=pb,
                                   interpret=interp)
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=plan.mesh,
         in_specs=(P(None, ax, None), _pool_specs(pool, ax),
                   P(None, None), P(None), P()),
-        out_specs=P(None, ax, None), check_rep=False)(
+        out_specs=P(None, ax, None), check_vma=False)(
             q, pool, table, cur_pos, jnp.asarray(window, jnp.int32))
 
 
@@ -222,9 +221,9 @@ def paged_attention_chunk_sharded(plan, q: jnp.ndarray, pool,
                                         cap=cap, impl=impl, pb=pb, qt=qt,
                                         interpret=interp)
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=plan.mesh,
         in_specs=(P(None, ax, None, None), _pool_specs(pool, ax),
                   P(None, None), P(None, None), P()),
-        out_specs=P(None, ax, None, None), check_rep=False)(
+        out_specs=P(None, ax, None, None), check_vma=False)(
             q, pool, table, q_pos, jnp.asarray(window, jnp.int32))

@@ -18,11 +18,12 @@ import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs import get, reduced
 from repro.data.pipeline import PipelineConfig, make_batch
+from repro.launch.mesh import make_host_mesh
 from repro.models import model as M
 from repro.train import trainer
 
 cfg = reduced(get("llama3-8b"), n_layers=2, d_model=64, d_ff=128, vocab=256)
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_host_mesh(n_model=4, n_data=2)
 mdict = dict(zip(mesh.axis_names, mesh.devices.shape))
 
 batch_np = make_batch(cfg, PipelineConfig(seed=0, global_batch=4, seq_len=32), 0)
@@ -62,10 +63,11 @@ import json
 import jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs import get, reduced
+from repro.launch.mesh import make_host_mesh
 from repro.models import model as M
 
 cfg = reduced(get("llama3-8b"), n_layers=2, d_model=64, d_ff=128, vocab=256)
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_host_mesh(n_model=4, n_data=2)
 params = M.init_params(cfg, jax.random.PRNGKey(0))
 B, S = 4, 16
 toks = jax.random.randint(jax.random.PRNGKey(1), (B, S), 0, cfg.vocab)

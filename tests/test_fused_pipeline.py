@@ -201,6 +201,37 @@ def test_tuner_stacked_params(rng):
     tune.clear()
 
 
+def test_tuner_reports_refused_native_candidates():
+    """Under native lowering a candidate the compiler refuses is kept with
+    its error in snapshot(); a key whose Pallas candidates all fail
+    raises instead of caching a marker (or quietly serving XLA)."""
+    tune.clear()
+    cands = tune.int8_candidates(256, 512)
+    refused = cands[1].label
+
+    def run(c):
+        if c.label == refused:
+            raise RuntimeError("Mosaic failed to compile TPU kernel\ndetail")
+        return jnp.zeros(())
+    best = tune.autotune(tune.int8_key(256, 512, 4, interpret=False),
+                         cands, run, reps=1, inner=1)
+    assert best.label != refused
+    entry = tune.snapshot()["int8/256/512/4/tpu"]
+    assert entry["failed"] == {
+        refused: "RuntimeError: Mosaic failed to compile TPU kernel"}
+
+    def run_xla_only(c):
+        if c.impl == "pallas":
+            raise RuntimeError("refused")
+        return jnp.zeros(())
+    key = tune.lut_key(256, 512, 4, interpret=False)
+    with pytest.raises(tune.TuneError, match="no Pallas candidate"):
+        tune.autotune(key, tune.lut_candidates(256, 512), run_xla_only,
+                      reps=1, inner=1)
+    assert tune.get(key) is None
+    tune.clear()
+
+
 # ---------------------------------------------- mode x dense_equivalent
 @pytest.mark.parametrize("mode", ["int8", "codebook4", "acsr", "aida"])
 def test_apply_fc_fused_epilogue_all_modes(rng, mode):

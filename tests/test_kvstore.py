@@ -223,8 +223,8 @@ def test_chunk_pallas_matches_xla(kv_dtype, pb, qt, window):
 
 @pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
 def test_chunk_c1_bit_identical_to_decode_kernel(kv_dtype):
-    """A C=1 chunk through the Pallas chunk kernel IS the decode kernel:
-    same grid arithmetic, bit-identical output."""
+    """Pallas decode is a one-query chunk of the chunk kernel: its
+    [B, H, Dh] entry point matches a C=1 chunk bit for bit."""
     B, Hkv, G, Dh, ps, npp, S = 2, 2, 3, 16, 4, 3, 10
     rng = np.random.default_rng(7)
     pool, table, _, _ = fill_pool(rng, B, Hkv, Dh, ps, npp, S, kv_dtype)

@@ -322,7 +322,8 @@ def test_serve_cli_trace_and_json(tmp_path):
          "--trace", str(trace), "--trace-ring", "32",
          "--json", str(mjson), "--report", str(report),
          "--slo", "ttft_p99=40,goodput=1.0"],
-        env=dict(os.environ, PYTHONPATH=src, REPRO_AUTOTUNE="0"),
+        env=dict(os.environ, PYTHONPATH=src, REPRO_AUTOTUNE="0",
+                 JAX_COMPILATION_CACHE_DIR=str(tmp_path / "jax_cache")),
         capture_output=True, text=True, timeout=1200)
     assert out.returncode == 0, out.stderr[-2000:]
     assert "trace:" in out.stdout and "json:" in out.stdout

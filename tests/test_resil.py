@@ -360,24 +360,27 @@ def test_resil_none_is_exact_noop(params):
 
 
 # ----------------------------------------------------------- CLI / bench
-def test_serve_cli_accepts_resil_flags():
+def test_serve_cli_accepts_resil_flags(tmp_path):
     import subprocess
     import sys
     import os
     src = os.path.join(os.path.dirname(__file__), "..", "src")
+    # the serve CLI keeps a compile cache; keep it out of the checkout
+    env = dict(os.environ, PYTHONPATH=src,
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "jax_cache"))
     out = subprocess.run(
         [sys.executable, "-m", "repro.launch.serve", "--arch",
          "llama3-8b", "--requests", "3", "--max-new", "4",
          "--fault-plan", "straggler:1", "--deadline-ticks", "64",
          "--max-retries", "1"],
-        env=dict(os.environ, PYTHONPATH=src), capture_output=True,
+        env=env, capture_output=True,
         text=True, timeout=1200)
     assert out.returncode == 0, out.stderr[-2000:]
     assert "resil:" in out.stdout
     bad = subprocess.run(
         [sys.executable, "-m", "repro.launch.serve", "--arch",
          "llama3-8b", "--fault-plan", "nope:1"],
-        env=dict(os.environ, PYTHONPATH=src), capture_output=True,
+        env=env, capture_output=True,
         text=True, timeout=600)
     assert bad.returncode != 0
     assert "unknown fault preset" in bad.stderr
