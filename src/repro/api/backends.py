@@ -30,10 +30,10 @@ class JaxDenseBackend(Executor):
     def make_decode_step(self, cfg, unroll: bool = False, plan=None):
         from repro.models import model as M
 
-        def step(params, state, tokens):
+        def serve_decode_step(params, state, tokens):
             return M.decode_step(cfg, params, state, tokens, unroll=unroll,
                                  plan=plan)
-        return step
+        return serve_decode_step
 
     def run_fc(self, layer, x):
         import jax.numpy as jnp

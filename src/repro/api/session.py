@@ -42,9 +42,10 @@ from __future__ import annotations
 import collections
 import contextlib
 import dataclasses
+import re
 import time
 import warnings
-from typing import Deque, List, Optional, Sequence, Tuple
+from typing import Deque, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -85,6 +86,13 @@ def resolve_kv_cache(kv_cache: Optional[str], cfg: ArchConfig) -> str:
 # Mesh sessions compile per session instead (their in/out shardings
 # depend on the session's concrete param/state trees).
 _STEP_CACHE: dict = {}
+
+#: model calls :attr:`Session.step_records` keeps, newest last
+STEP_RECORDS = 8192
+
+#: one instruction of compiled HLO text and its ``op_name`` metadata
+_HLO_OP = re.compile(r'^\s*(?:ROOT )?%?([\w.\-]+) = .*?op_name="([^"]*)"',
+                     re.M)
 
 
 def _jitted_step(backend: Executor, cfg: ArchConfig):
@@ -232,6 +240,13 @@ class Session:
         self.tick = 0              # scheduling-opportunity clock
         self.stats = {"steps": 0, "fills": 0, "preemptions": 0,
                       "chunk": self.chunk}
+        # one record per model call (see _advance); _emitted and _h2d
+        # gather the open call's (rid, token) pairs and host->device bytes
+        self.step_records: Deque[dict] = collections.deque(
+            maxlen=STEP_RECORDS)
+        self._emitted: List[Tuple[int, int]] = []
+        self._h2d = 0
+        self._step_ann = None      # the open session.step annotation
         if kv_cache == "paged":
             self.stats.update({"page_allocs": 0, "pages_in_use": 0,
                                "pages_peak": 0, "pages_reclaimed_swa": 0,
@@ -262,12 +277,41 @@ class Session:
             if self.resil.watchdog is not None:
                 self.resil.watchdog.obs = hook
 
-    def _step_ctx(self, phase: str):
-        """Wall-clock phase accounting around the jitted step (tracing
-        on only); wall times never enter the tick-clock event stream."""
-        if not self.tracer.enabled:
-            return contextlib.nullcontext()
-        return self.tracer.wall.phase(phase)
+    @contextlib.contextmanager
+    def _call_span(self):
+        """The ``session.step`` span around one scheduling opportunity:
+        slot filling, then the model call if any slot is active (which
+        sets its ``kind``)."""
+        with obs_mod.span("session.step", self.tracer,
+                          step=self.stats["steps"]) as ann:
+            self._step_ann = ann
+            try:
+                yield
+            finally:
+                self._step_ann = None
+
+    def op_scopes(self) -> Dict[str, Dict[str, str]]:
+        """``{XLA module: {HLO instruction: op_name metadata}}`` of this
+        session's compiled step programs (``jit_serve_decode_step``,
+        ``jit_serve_chunked_step``), for laying the device ops of a
+        ``jax.profiler`` trace at the ``jax.named_scope`` that made
+        them (``kv.write``, ``kv.read``, ``attention``, ``proj``,
+        ``logits``).  Lowers and compiles the steps once more on each
+        call (the compile cache answers where it is on)."""
+        b = self.slots
+        calls = [(self._step, (self.params, self.state,
+                               jnp.zeros((b,), jnp.int32)))]
+        if self._prefill is not None:
+            calls.append((self._prefill, (
+                self.params, self.state, jnp.zeros((b, self.chunk),
+                                                   jnp.int32),
+                jnp.zeros((b,), jnp.int32))))
+        out = {}
+        for fn, args in calls:
+            text = fn.lower(*args).compile().as_text()
+            module = re.search(r"HloModule (\S+?),", text).group(1)
+            out[module] = dict(_HLO_OP.findall(text))
+        return out
 
     # ------------------------------------------------------------ public
     def submit(self, req: Request) -> None:
@@ -333,8 +377,12 @@ class Session:
                 self.submit(pending.popleft()[1])
             if self.resil is not None:
                 self._resil_tick(clock)
-            self._fill_slots()
-            if all(e is None for e in self.slot_entry):
+            with self._call_span():
+                self._fill_slots()
+                idle = all(e is None for e in self.slot_entry)
+                if not idle:
+                    self._try_advance()
+            if idle:
                 if self._fault_waiting():
                     # an injected page spike is holding the pool hostage;
                     # burn the tick so the window can pass instead of
@@ -350,27 +398,31 @@ class Session:
                     clock = pending[0][0]
                     continue
                 break
-            try:
-                self._advance()
-            except rsl.InjectedFault as f:
-                # deliberately injected step failure (role-stall /
-                # straggler): the tick is lost, the work is not
-                self.resil.count("fault_steps")
-                self.tracer.instant("fault.injected", tick=self.tick,
-                                    role=self.role,
-                                    fault=f.fault_class)
-            except kvs.OutOfPages:
-                if self.resil is not None and self.alloc is not None \
-                        and self.alloc.holdback > 0:
-                    # page-spike squeezed even the last runner; wait the
-                    # window out (pages come back, recompute resumes)
-                    self.resil.count("wait_ticks")
-                else:
-                    raise
             clock += 1
         else:
             self._incomplete(on_incomplete, blocked=False, pending=pending)
         return sorted(self.results, key=lambda r: r.rid)
+
+    def _try_advance(self) -> None:
+        """One model call of the co-located loop; an injected fault or a
+        spike-squeezed pool loses the tick, not the work."""
+        try:
+            self._advance()
+        except rsl.InjectedFault as f:
+            # deliberately injected step failure (role-stall /
+            # straggler): the tick is lost, the work is not
+            self.resil.count("fault_steps")
+            self.tracer.instant("fault.injected", tick=self.tick,
+                                role=self.role,
+                                fault=f.fault_class)
+        except kvs.OutOfPages:
+            if self.resil is not None and self.alloc is not None \
+                    and self.alloc.holdback > 0:
+                # page-spike squeezed even the last runner; wait the
+                # window out (pages come back, recompute resumes)
+                self.resil.count("wait_ticks")
+            else:
+                raise
 
     # ----------------------------------------------------------- internals
     def _incomplete(self, on_incomplete: str, blocked: bool,
@@ -526,14 +578,18 @@ class Session:
         return self._page_need(entry) - len(hits) <= avail
 
     def _fill_slots(self):
-        for i in range(self.slots):
-            if self.slot_entry[i] is not None:
-                continue
-            entry = self.sched.next_entry(self._fits,
-                                          step=self.stats["steps"])
-            if entry is None:
-                break
-            self._admit(i, entry)
+        with obs_mod.span("session.admit", self.tracer,
+                          step=self.stats["steps"]) as ann:
+            for i in range(self.slots):
+                if self.slot_entry[i] is not None:
+                    continue
+                entry = self.sched.next_entry(self._fits,
+                                              step=self.stats["steps"])
+                if entry is None:
+                    break
+                self._admit(i, entry)
+            if any(e is not None for e in self.slot_entry):
+                ann.set_metadata(kind=self._step_kind())
 
     def _admit(self, i: int, entry: schd.SchedEntry):
         req = entry.req
@@ -581,6 +637,7 @@ class Session:
             return
         pj = jnp.asarray([a[0] for a in attached], jnp.int32)
         pids = jnp.asarray([a[1] for a in attached], jnp.int32)
+        self._h2d += pj.nbytes + pids.nbytes
         self.state["page_table"] = \
             self.state["page_table"].at[i, pj].set(pids)
         skip = len(attached) * self.page_size
@@ -654,11 +711,11 @@ class Session:
         self.sched.requeue(entry)
         self.stats["preemptions"] += 1
 
-    def _ensure_pages(self, counts: List[int]) -> None:
+    def _ensure_pages(self, counts: List[int]) -> int:
         """Host-side page faults: before a step, make sure each active
         slot owns every page its next ``counts[i]`` tokens land in; fresh
         pages get their quantization scales cleared so stale maxima can't
-        poison them."""
+        poison them.  Returns the number of pages granted."""
         npp = self.host_table.shape[1]
         events = []
         try:
@@ -682,9 +739,10 @@ class Session:
             self.alloc.free(pid for _, _, pid in events)
             raise
         if not events:
-            return
+            return 0
         si, pi, pids = (jnp.asarray([e[n] for e in events], jnp.int32)
                         for n in range(3))
+        self._h2d += si.nbytes + pi.nbytes + pids.nbytes
         self.state["page_table"] = \
             self.state["page_table"].at[si, pi].set(pids)
         kv = self.state["layers"]["kv"]
@@ -697,16 +755,17 @@ class Session:
         self.stats["page_allocs"] = self.alloc.total_allocs
         self.stats["pages_in_use"] = self.alloc.in_use
         self.stats["pages_peak"] = self.alloc.peak
+        return len(events)
 
-    def _ensure_pages_or_preempt(self, counts: List[int]) -> None:
+    def _ensure_pages_or_preempt(self, counts: List[int]) -> int:
         """Resolve page pressure: allocate; on OutOfPages release prefix
         pins LRU-first, then preempt the youngest slot, until the
         remaining batch fits.  The last runner is never preempted — a
-        pool too small for a single request still raises."""
+        pool too small for a single request still raises.  Returns the
+        number of pages granted."""
         while True:
             try:
-                self._ensure_pages(counts)
-                return
+                return self._ensure_pages(counts)
             except kvs.OutOfPages:
                 if self.prefix is not None \
                         and self.prefix.release(self.alloc, 1):
@@ -738,6 +797,7 @@ class Session:
             return
         si = jnp.asarray([e[0] for e in events], jnp.int32)
         pi = jnp.asarray([e[1] for e in events], jnp.int32)
+        self._h2d += si.nbytes + pi.nbytes
         self.state["page_table"] = self.state["page_table"].at[si, pi].set(
             jnp.int32(kvs.NO_PAGE))
         self.stats["pages_reclaimed_swa"] += len(events)
@@ -766,20 +826,43 @@ class Session:
                 self._insert_slot_prefix(i, entry)
 
     # ------------------------------------------------------------ stepping
+    def _step_kind(self) -> str:
+        """The next model call: ``chunked`` while an active slot still
+        has prompt to feed (chunked prefill on), else ``decode``."""
+        if self.chunk > 1 and any(self.slot_pending[i]
+                                  for i, e in enumerate(self.slot_entry)
+                                  if e is not None):
+            return "chunked"
+        return "decode"
+
     def _advance(self):
+        """One model call.  Its host work sits in ``session.*`` spans
+        (obs.span) that carry the call's ``step`` (model calls before
+        it) and ``kind``, and it leaves one record in
+        :attr:`step_records`."""
         if self.resil is not None and self.resil.plan is not None:
             # fault seam: a stalled/straggling role loses the whole tick
             # (raises InjectedFault before any state is touched)
             self.resil.plan.check_step(self.role, self.tick)
-        if self.chunk > 1 and any(self.slot_pending[i]
-                                  for i, e in enumerate(self.slot_entry)
-                                  if e is not None):
-            self._advance_chunked()
-        else:
-            self._advance_decode()
-        if self.kv_cache == "paged":
-            self._reclaim_swa_pages()
-            self._insert_prefix_pages()
+        args = {"step": self.stats["steps"], "kind": self._step_kind()}
+        if self._step_ann is not None:
+            self._step_ann.set_metadata(kind=args["kind"])
+        step = self._advance_chunked if args["kind"] == "chunked" \
+            else self._advance_decode
+        counts, granted, d2h = step(args)
+        if self.kv_cache == "paged" and (self._swa_window is not None
+                                         or self.prefix is not None):
+            with obs_mod.span("session.pages", self.tracer, **args):
+                self._reclaim_swa_pages()
+                self._insert_prefix_pages()
+        self.step_records.append({
+            **args, "tokens": sum(counts),
+            "sampled": len(self._emitted),
+            "active": sum(1 for c in counts if c),
+            "pages_granted": granted, "d2h_bytes": d2h,
+            "h2d_bytes": self._h2d, "emitted": self._emitted})
+        self._h2d = 0
+        self._emitted = []
 
     def _active_counts(self, chunk: int) -> List[int]:
         counts = [0] * self.slots
@@ -790,24 +873,30 @@ class Session:
                 if self.slot_pending[i] else 1
         return counts
 
-    def _advance_decode(self):
-        """One token per active slot through the backend's decode step."""
+    def _advance_decode(self, args: dict) -> Tuple[List[int], int, int]:
+        """One token per active slot through the backend's decode step;
+        returns (tokens fed per slot, pages granted, logits bytes read
+        back)."""
         counts = self._active_counts(1)
+        granted = 0
         if self.kv_cache == "paged":
-            self._ensure_pages_or_preempt(counts)
-        tokens = np.zeros((self.slots,), np.int32)
-        for i, entry in enumerate(self.slot_entry):
-            if entry is None:
-                continue
-            if self.slot_pending[i]:
-                tokens[i] = self.slot_pending[i][0]
-            elif self.slot_out[i]:
-                tokens[i] = self.slot_out[i][-1]
-            else:
-                tokens[i] = entry.req.prompt[-1]
-        with self._step_ctx("decode"):
-            self.state, logits = self._step(self.params, self.state,
-                                            jnp.asarray(tokens))
+            with obs_mod.span("session.pages", self.tracer, **args):
+                granted = self._ensure_pages_or_preempt(counts)
+        with obs_mod.span("session.feed", self.tracer, **args):
+            tokens = np.zeros((self.slots,), np.int32)
+            for i, entry in enumerate(self.slot_entry):
+                if entry is None:
+                    continue
+                if self.slot_pending[i]:
+                    tokens[i] = self.slot_pending[i][0]
+                elif self.slot_out[i]:
+                    tokens[i] = self.slot_out[i][-1]
+                else:
+                    tokens[i] = entry.req.prompt[-1]
+            fed = jnp.asarray(tokens)
+            self._h2d += tokens.nbytes
+        with obs_mod.span("session.dispatch", self.tracer, **args):
+            self.state, logits = self._step(self.params, self.state, fed)
         self.stats["steps"] += 1
         self.tracer.span("step.decode", tick=self.tick, role=self.role,
                          active=sum(1 for c in counts if c),
@@ -817,36 +906,44 @@ class Session:
             for i, entry in enumerate(self.slot_entry):
                 if entry is not None:
                     self.slot_pos[i] += 1
-        logits = np.asarray(logits[:, : self.cfg.vocab])
-        for i, entry in enumerate(self.slot_entry):
-            if entry is None:
-                continue
-            if self.slot_pending[i]:
-                self.slot_pending[i].pop(0)
+        with obs_mod.span("session.readback", self.tracer, **args):
+            logits = np.asarray(logits[:, : self.cfg.vocab])
+        with obs_mod.span("session.sample", self.tracer, **args):
+            for i, entry in enumerate(self.slot_entry):
+                if entry is None:
+                    continue
                 if self.slot_pending[i]:
-                    continue  # still prefilling
-            self._emit(i, logits[i], now)
+                    self.slot_pending[i].pop(0)
+                    if self.slot_pending[i]:
+                        continue  # still prefilling
+                self._emit(i, logits[i], now)
+        return counts, granted, logits.nbytes
 
-    def _advance_chunked(self):
+    def _advance_chunked(self, args: dict) -> Tuple[List[int], int, int]:
         """Mixed prefill+decode step: up to ``chunk`` prompt tokens per
-        prefilling slot, 1 token per decoding slot, all in one call."""
+        prefilling slot, 1 token per decoding slot, all in one call;
+        returns what :meth:`_advance_decode` does."""
         counts = self._active_counts(self.chunk)
-        self._ensure_pages_or_preempt(counts)
-        tokens = np.zeros((self.slots, self.chunk), np.int32)
-        for i, entry in enumerate(self.slot_entry):
-            if entry is None:
-                continue
-            if self.slot_pending[i]:
-                k = counts[i]
-                tokens[i, :k] = self.slot_pending[i][:k]
-            elif self.slot_out[i]:
-                tokens[i, 0] = self.slot_out[i][-1]
-            else:
-                tokens[i, 0] = entry.req.prompt[-1]
-        with self._step_ctx("prefill"):
-            self.state, logits = self._prefill(
-                self.params, self.state, jnp.asarray(tokens),
-                jnp.asarray(counts, jnp.int32))
+        with obs_mod.span("session.pages", self.tracer, **args):
+            granted = self._ensure_pages_or_preempt(counts)
+        with obs_mod.span("session.feed", self.tracer, **args):
+            tokens = np.zeros((self.slots, self.chunk), np.int32)
+            for i, entry in enumerate(self.slot_entry):
+                if entry is None:
+                    continue
+                if self.slot_pending[i]:
+                    k = counts[i]
+                    tokens[i, :k] = self.slot_pending[i][:k]
+                elif self.slot_out[i]:
+                    tokens[i, 0] = self.slot_out[i][-1]
+                else:
+                    tokens[i, 0] = entry.req.prompt[-1]
+            n_tok = np.asarray(counts, np.int32)
+            fed = (jnp.asarray(tokens), jnp.asarray(n_tok))
+            self._h2d += tokens.nbytes + n_tok.nbytes
+        with obs_mod.span("session.dispatch", self.tracer, **args):
+            self.state, logits = self._prefill(self.params, self.state,
+                                               *fed)
         self.stats["steps"] += 1
         self.tracer.span("step.prefill", tick=self.tick, role=self.role,
                          active=sum(1 for c in counts if c),
@@ -855,15 +952,18 @@ class Session:
         for i, entry in enumerate(self.slot_entry):
             if entry is not None:
                 self.slot_pos[i] += counts[i]
-        logits = np.asarray(logits[:, :, : self.cfg.vocab])
-        for i, entry in enumerate(self.slot_entry):
-            if entry is None:
-                continue
-            if self.slot_pending[i]:
-                del self.slot_pending[i][:counts[i]]
+        with obs_mod.span("session.readback", self.tracer, **args):
+            logits = np.asarray(logits[:, :, : self.cfg.vocab])
+        with obs_mod.span("session.sample", self.tracer, **args):
+            for i, entry in enumerate(self.slot_entry):
+                if entry is None:
+                    continue
                 if self.slot_pending[i]:
-                    continue  # still prefilling
-            self._emit(i, logits[i, counts[i] - 1], now)
+                    del self.slot_pending[i][:counts[i]]
+                    if self.slot_pending[i]:
+                        continue  # still prefilling
+                self._emit(i, logits[i, counts[i] - 1], now)
+        return counts, granted, logits.nbytes
 
     def _emit(self, i: int, logits_i: np.ndarray, now: float):
         """Sample the next token for slot ``i`` from this step's logits;
@@ -877,6 +977,7 @@ class Session:
         else:
             nxt = int(logits_i.argmax())
         self.slot_out[i].append(nxt)
+        self._emitted.append((req.rid, nxt))
         rec = entry.record
         if rec["first_token_time"] is None:
             rec["first_token_time"] = now

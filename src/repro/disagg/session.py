@@ -323,14 +323,16 @@ class DisaggSession:
                 self.submit(pending.popleft()[1])
             if self.resil is not None:
                 self._resil_tick()
-            self._admit_handoffs()
-            if self.dec.sched.queue:   # resil handoff-timeout fallback
-                self.dec._fill_slots()
-            dec_busy = any(e is not None for e in self.dec.slot_entry)
-            dec_ran = dec_busy and self._step_role(self.dec, "decode")
-            self.pre._fill_slots()
-            pre_busy = any(e is not None for e in self.pre.slot_entry)
-            pre_ran = pre_busy and self._step_role(self.pre, "prefill")
+            with self.dec._call_span():
+                self._admit_handoffs()
+                if self.dec.sched.queue:   # resil handoff-timeout fallback
+                    self.dec._fill_slots()
+                dec_busy = any(e is not None for e in self.dec.slot_entry)
+                dec_ran = dec_busy and self._step_role(self.dec, "decode")
+            with self.pre._call_span():
+                self.pre._fill_slots()
+                pre_busy = any(e is not None for e in self.pre.slot_entry)
+                pre_ran = pre_busy and self._step_role(self.pre, "prefill")
             self.ticks += 1
             self.stats["ticks"] = self.ticks
             self.stats["prefill_busy_ticks"] += int(pre_ran)

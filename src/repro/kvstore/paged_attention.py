@@ -90,8 +90,9 @@ def paged_attention_xla_chunk(q: jnp.ndarray, pool: PagedKV,
     npp = table.shape[1]
     scale = (dh ** -0.5) if scale is None else scale
     safe = jnp.maximum(table, poolmod.GARBAGE_PAGE)
-    k = jnp.take(pool.k_pages, safe, axis=0)       # [B, P, Hkv, ps, Dh]
-    v = jnp.take(pool.v_pages, safe, axis=0)
+    with jax.named_scope("kv.read"):
+        k = jnp.take(pool.k_pages, safe, axis=0)   # [B, P, Hkv, ps, Dh]
+        v = jnp.take(pool.v_pages, safe, axis=0)
     # unquantized pages mirror _core's mixed precision (bf16 operands,
     # f32 accumulate/softmax) so paged bf16 == full cache up to reduction
     # order; int8 pages contract in f32 (dequant headroom)
@@ -103,7 +104,8 @@ def paged_attention_xla_chunk(q: jnp.ndarray, pool: PagedKV,
     s = jnp.einsum("bkgqd,bpkcd->bkgqpc", qg, k.astype(cdt),
                    preferred_element_type=jnp.float32) * scale
     if pool.quantized:
-        ks = jnp.take(pool.k_scale, safe, axis=0)  # [B, P, Hkv]
+        with jax.named_scope("kv.read"):
+            ks = jnp.take(pool.k_scale, safe, axis=0)   # [B, P, Hkv]
         s = s * ks.transpose(0, 2, 1)[:, :, None, None, :, None]
     s = _softcap(s, cap)
     mask = poolmod.chunk_attention_mask(
@@ -113,7 +115,8 @@ def paged_attention_xla_chunk(q: jnp.ndarray, pool: PagedKV,
     p = jax.nn.softmax(s.reshape(b, hkv, g, c, npp * ps), axis=-1)
     p = p.reshape(b, hkv, g, c, npp, ps)
     if pool.quantized:
-        vs = jnp.take(pool.v_scale, safe, axis=0)
+        with jax.named_scope("kv.read"):
+            vs = jnp.take(pool.v_scale, safe, axis=0)
         p = p * vs.transpose(0, 2, 1)[:, :, None, None, :, None]
     o = jnp.einsum("bkgqpc,bpkcd->bkgqd", p.astype(cdt), v.astype(cdt),
                    preferred_element_type=jnp.float32)
@@ -343,6 +346,7 @@ def resolve_paged_chunk(batch: int, h: int, chunk: int, d_head: int,
     return ("xla" if interp else "pallas"), 2, chunk, interp
 
 
+@jax.named_scope("attention")
 def paged_attention(q, pool: PagedKV, table, cur_pos, window, *,
                     scale: Optional[float] = None,
                     cap: Optional[float] = None,
@@ -367,6 +371,7 @@ def paged_attention(q, pool: PagedKV, table, cur_pos, window, *,
                                   pb=pb or 2, interpret=interpret)
 
 
+@jax.named_scope("attention")
 def paged_attention_chunk(q, pool: PagedKV, table, q_pos, window, *,
                           scale: Optional[float] = None,
                           cap: Optional[float] = None,

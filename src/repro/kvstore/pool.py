@@ -114,6 +114,7 @@ def _write_page_rescale(pages, scale, new, new_s, safe_page, slot):
     return pages, scale
 
 
+@jax.named_scope("kv.write")
 def update(pool: PagedKV, table: jnp.ndarray, k_new: jnp.ndarray,
            v_new: jnp.ndarray, cur_pos: jnp.ndarray,
            valid: Optional[jnp.ndarray] = None) -> PagedKV:
@@ -173,6 +174,7 @@ def update(pool: PagedKV, table: jnp.ndarray, k_new: jnp.ndarray,
     return jax.lax.cond(grow, slow, fast, pool)
 
 
+@jax.named_scope("kv.write")
 def update_chunk(pool: PagedKV, table: jnp.ndarray, k_new: jnp.ndarray,
                  v_new: jnp.ndarray, positions: jnp.ndarray,
                  valid: Optional[jnp.ndarray] = None) -> PagedKV:

@@ -156,10 +156,12 @@ def decode_attend(cache: kvc.KVCache, q, k, v, cur_pos, *, window,
     update + masked softmax over the slots.  Split out from attn_decode
     so benchmarks can time the attention/KV term separately from the
     (compressible) FC projections."""
-    cache = kvc.update(cache, k, v, cur_pos, ring=ring)
-    mask = kvc.attention_mask(cache, cur_pos,
-                              jnp.asarray(window, jnp.int32))  # [B, S]
-    o = _core(q, cache.k, cache.v, mask[:, None, None, :], cap, scale)
+    with jax.named_scope("kv.write"):
+        cache = kvc.update(cache, k, v, cur_pos, ring=ring)
+    with jax.named_scope("attention"):
+        mask = kvc.attention_mask(cache, cur_pos,
+                                  jnp.asarray(window, jnp.int32))  # [B, S]
+        o = _core(q, cache.k, cache.v, mask[:, None, None, :], cap, scale)
     return cache, o
 
 

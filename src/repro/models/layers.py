@@ -30,6 +30,7 @@ def dense_init(key, d_in: int, d_out: int, scale: Optional[float] = None):
     return jax.random.normal(key, (d_in, d_out), jnp.float32) * scale
 
 
+@jax.named_scope("proj")
 def dense(x, w, bias=None, activation=None, plan=None):
     """act(x @ w + bias).  ``w`` may be a raw [d_in, d_out] matrix OR any
     compressed leaf registered with repro.api.dispatch (e.g. a
@@ -126,6 +127,7 @@ def _act(name: str, x):
     raise ValueError(name)
 
 
+@jax.named_scope("proj")
 def mlp(x, p, act: str = "silu", plan=None):
     if "gate" in p:
         # activation fuses into the gate projection's kernel epilogue

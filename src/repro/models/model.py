@@ -279,17 +279,23 @@ def decode_step(cfg: ArchConfig, params: Dict, state: Dict,
     new_layers, x = tfm.stack_decode(cfg, params["layers"], state["layers"],
                                      x, state["pos"], unroll=unroll,
                                      page_table=table, plan=plan)
+    new_state = {"layers": new_layers, "pos": state["pos"] + 1}
+    if table is not None:
+        new_state["page_table"] = table
+    return new_state, serve_logits(cfg, params, x)[:, 0, :]
+
+
+@jax.named_scope("logits")
+def serve_logits(cfg: ArchConfig, params: Dict, x) -> jnp.ndarray:
+    """Final norm, unembedding and softcap of the serving steps:
+    x [B, S, D] -> logits [B, S, Vpad] f32."""
     x = _norm(cfg)(x, params["final_norm"])
     if cfg.tie_embeddings:
         logits = unembed(x, params["embed"])
     else:
         logits = jnp.matmul(x, params["lm_head"].astype(COMPUTE_DTYPE),
                             preferred_element_type=jnp.float32)
-    new_state = {"layers": new_layers, "pos": state["pos"] + 1}
-    if table is not None:
-        new_state["page_table"] = table
-    logits = softcap(logits, cfg.final_softcap)
-    return new_state, logits[:, 0, :]
+    return softcap(logits, cfg.final_softcap)
 
 
 def state_specs(cfg: ArchConfig, batch: int, dp_ok: bool,

@@ -17,8 +17,10 @@ Three layers, one package:
   path, queueing split, role utilization, page-pool pressure — and
   score it against a declarative :class:`SLOSpec`.
 
-Plus :func:`timeit` (the one best-of-N wall timer) and
-:func:`profile_trace` (optional ``jax.profiler`` hook).
+Plus :func:`timeit` (the one best-of-N wall timer),
+:func:`profile_trace` (optional ``jax.profiler`` hook), :class:`span`
+(a host span on the profiler's clock, also feeding ``tracer.wall``) and
+:data:`compile_events` (every trace and compile of the process).
 """
 from repro.obs.analyze import SLOSpec, TraceReport, analyze, load_trace
 from repro.obs.recorder import FlightRecorder
@@ -26,11 +28,11 @@ from repro.obs.registry import (Counter, Gauge, Histogram, Registry,
                                 percentile, provenance)
 from repro.obs.timing import timeit
 from repro.obs.trace import (NULL, NullTracer, Tracer, WallTimers,
-                             profile_trace)
+                             compile_events, profile_trace, span)
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "Registry", "percentile",
     "provenance", "FlightRecorder", "timeit", "NULL", "NullTracer",
-    "Tracer", "WallTimers", "profile_trace",
+    "Tracer", "WallTimers", "profile_trace", "span", "compile_events",
     "SLOSpec", "TraceReport", "analyze", "load_trace",
 ]

@@ -21,13 +21,24 @@ The Chrome/Perfetto ``trace_event`` exporter maps roles to processes
 and slots to threads: load the exported JSON in https://ui.perfetto.dev
 and a serve run renders as a per-role, per-slot timeline (one tick =
 :data:`TICK_US` microseconds on the rendered axis).
+
+Beside the tick clock sits the profiler's clock: :class:`span` opens a
+``jax.profiler.TraceAnnotation``, so the serving session's host spans
+(``session.step`` and its children) share a ``jax.profiler`` trace with
+the device's program and op events, and :data:`compile_events` logs every
+trace and compile of the process on ``time.perf_counter``.
 """
 from __future__ import annotations
 
+import collections
 import contextlib
 import json
 import time
-from typing import Callable, Dict, List, Optional
+from typing import Deque, Dict, List, Optional, Tuple
+
+import jax
+from jax import monitoring
+from jax.profiler import TraceAnnotation
 
 #: microseconds one scheduler tick occupies on the exported timeline
 #: (purely presentational: ticks are the real clock)
@@ -67,9 +78,6 @@ class NullTracer:
 
     def crash(self, reason, **context):
         pass
-
-    def hook(self, role="engine", clock=None):
-        return None
 
 
 #: the shared disabled tracer — sessions default to this
@@ -121,17 +129,6 @@ class Tracer:
             return None
         return self.recorder.dump(reason=reason, context=context)
 
-    def hook(self, role: str = "engine",
-             clock: Optional[Callable[[], int]] = None) -> Callable:
-        """A ``(name, **args) -> None`` emitter bound to a role and a
-        tick-clock callable — the shape the allocator / prefix-cache /
-        scheduler seams accept so they stay import-light."""
-        if clock is None:
-            return lambda name, **a: self.instant(name, tick=0,
-                                                  role=role, **a)
-        return lambda name, **a: self.instant(name, tick=clock(),
-                                              role=role, **a)
-
     # ---------------------------------------------------------- export
     def to_chrome(self) -> dict:
         """Chrome/Perfetto ``trace_event`` JSON: roles become processes,
@@ -178,25 +175,18 @@ class Tracer:
 
 
 class WallTimers:
-    """Wall-clock phase accumulators (decode / prefill / migrate ...).
-
-    Deliberately separate from the event stream: wall time is host noise
-    and would break replay-identical traces, but the per-phase split is
-    exactly the EIE-style accounting the BENCH trajectory needs."""
+    """Wall-clock seconds and calls per :class:`span` name (``tracer.wall``
+    of a live tracer): a view of the host spans, never a timer of its
+    own.  Kept apart from the event stream: wall time is host noise and
+    would break replay-identical traces."""
 
     def __init__(self):
         self.seconds: Dict[str, float] = {}
         self.calls: Dict[str, int] = {}
 
-    @contextlib.contextmanager
-    def phase(self, name: str):
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            dt = time.perf_counter() - t0
-            self.seconds[name] = self.seconds.get(name, 0.0) + dt
-            self.calls[name] = self.calls.get(name, 0) + 1
+    def add(self, name: str, seconds: float) -> None:
+        self.seconds[name] = self.seconds.get(name, 0.0) + seconds
+        self.calls[name] = self.calls.get(name, 0) + 1
 
     def summary(self) -> dict:
         total = sum(self.seconds.values())
@@ -207,6 +197,56 @@ class WallTimers:
                 for name in sorted(self.seconds)}
 
 
+class span:
+    """A host span on the profiler's clock: ``with obs.span(name, tracer,
+    **args) as ann`` opens ``jax.profiler.TraceAnnotation(name, **args)``
+    (``ann.set_metadata(...)`` adds arguments while it is open), which
+    lands in a ``jax.profiler`` trace beside the device's ``XLA Modules``
+    / ``XLA Ops`` events and costs one inactive annotation when no trace
+    is running.  With a live ``tracer`` its wall time also adds to
+    ``tracer.wall`` under ``name``."""
+
+    __slots__ = ("_name", "_ann", "_wall", "_t0")
+
+    def __init__(self, name: str, tracer=None, **args):
+        self._name = name
+        self._ann = TraceAnnotation(name, **args)
+        self._wall = tracer.wall if tracer is not None and tracer.enabled \
+            else None
+
+    def __enter__(self):
+        self._ann.__enter__()
+        if self._wall is not None:
+            self._t0 = time.perf_counter()
+        return self._ann
+
+    def __exit__(self, *exc):
+        if self._wall is not None:
+            self._wall.add(self._name, time.perf_counter() - self._t0)
+        return self._ann.__exit__(*exc)
+
+
+#: ``(time.perf_counter() at its end, "trace:<fn>" | "compile:<fn>",
+#: seconds)`` of every jaxpr trace and every backend compile or
+#: persistent-cache load in this process, newest last
+compile_events: Deque[Tuple[float, str, float]] = \
+    collections.deque(maxlen=4096)
+
+_COMPILE_EVENTS = {"/jax/core/compile/jaxpr_trace_duration": "trace",
+                   "/jax/core/compile/backend_compile_duration": "compile"}
+
+
+def _on_duration(event: str, seconds: float, **kw) -> None:
+    kind = _COMPILE_EVENTS.get(event)
+    if kind is not None:
+        compile_events.append((time.perf_counter(),
+                               f"{kind}:{kw.get('fun_name', '?')}",
+                               seconds))
+
+
+monitoring.register_event_duration_secs_listener(_on_duration)
+
+
 @contextlib.contextmanager
 def profile_trace(log_dir: Optional[str]):
     """Optional ``jax.profiler`` trace around the compiled steps: a
@@ -215,7 +255,6 @@ def profile_trace(log_dir: Optional[str]):
     if not log_dir:
         yield
         return
-    import jax
     jax.profiler.start_trace(log_dir)
     try:
         yield

@@ -39,7 +39,8 @@ from repro import kvstore as kvs
 from repro.configs.base import ArchConfig
 from repro.models import attention as attn
 from repro.models import moe as moe_mod
-from repro.models.layers import COMPUTE_DTYPE, embed, mlp, softcap, unembed
+from repro.models.layers import COMPUTE_DTYPE, embed, mlp
+from repro.models.model import serve_logits
 from repro.models.transformer import _norm
 
 
@@ -132,16 +133,9 @@ def prefill_step(cfg: ArchConfig, params: Dict, state: Dict,
         x = x * jnp.asarray(cfg.d_model ** 0.5, COMPUTE_DTYPE)
     new_layers, x = _stack_prefill(cfg, params["layers"], state["layers"],
                                    x, positions, valid, table, plan=plan)
-    x = _norm(cfg)(x, params["final_norm"])
-    if cfg.tie_embeddings:
-        logits = unembed(x, params["embed"])
-    else:
-        logits = jnp.matmul(x, params["lm_head"].astype(COMPUTE_DTYPE),
-                            preferred_element_type=jnp.float32)
-    logits = softcap(logits, cfg.final_softcap)
     new_state = {"layers": new_layers, "pos": state["pos"] + n_tok,
                  "page_table": table}
-    return new_state, logits
+    return new_state, serve_logits(cfg, params, x)
 
 
 # Compiled chunk steps keyed by (cfg, C): the step is backend-agnostic
@@ -162,16 +156,14 @@ def make_prefill_step(cfg: ArchConfig, chunk: int, plan=None,
     plan=None steps are cached per (cfg, chunk); mesh steps compile per
     session because their in/out shardings depend on the session's
     concrete param/state trees."""
+    def serve_chunked_step(params, state, tokens, n_tok):
+        return prefill_step(cfg, params, state, tokens, n_tok, plan=plan)
+
     if plan is not None:
-        return jax.jit(
-            lambda params, state, tokens, n_tok:
-            prefill_step(cfg, params, state, tokens, n_tok, plan=plan),
-            in_shardings=in_shardings, out_shardings=out_shardings,
-            donate_argnums=(1,))
+        return jax.jit(serve_chunked_step, in_shardings=in_shardings,
+                       out_shardings=out_shardings, donate_argnums=(1,))
     key = (cfg, chunk)
     if key not in _PREFILL_CACHE:
-        _PREFILL_CACHE[key] = jax.jit(
-            lambda params, state, tokens, n_tok:
-            prefill_step(cfg, params, state, tokens, n_tok),
-            donate_argnums=(1,))
+        _PREFILL_CACHE[key] = jax.jit(serve_chunked_step,
+                                      donate_argnums=(1,))
     return _PREFILL_CACHE[key]

@@ -160,14 +160,17 @@ def test_timeit_returns_best_per_call():
 
 
 def test_wall_timers_phases():
-    w = obs.WallTimers()
-    with w.phase("decode"):
+    t = obs.Tracer()
+    with obs.span("decode", t):
         pass
-    with w.phase("decode"):
+    with obs.span("decode", t, step=1):
         pass
-    with w.phase("prefill"):
+    with obs.span("prefill", t):
         pass
-    s = w.summary()
+    with obs.span("untimed"):          # no live tracer: profiler only
+        pass
+    s = t.wall.summary()
+    assert set(s) == {"decode", "prefill"}
     assert s["decode"]["calls"] == 2 and s["prefill"]["calls"] == 1
     assert abs(sum(v["share"] for v in s.values()) - 1.0) < 1e-6
 
@@ -342,4 +345,6 @@ def test_serve_cli_trace_and_json(tmp_path):
     assert m["provenance"]["config"] == "llama3-8b-smoke"
     assert m["metrics"]["completed"] == 3
     assert m["pages"]["leaked"] == 0
-    assert m["wall_phases"]["decode"]["calls"] >= 1
+    phases = m["wall_phases"]
+    assert phases["session.dispatch"]["calls"] >= 1
+    assert phases["session.readback"]["calls"] >= 1
