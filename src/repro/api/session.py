@@ -953,7 +953,7 @@ class Session:
             if entry is not None:
                 self.slot_pos[i] += counts[i]
         with obs_mod.span("session.readback", self.tracer, **args):
-            logits = np.asarray(logits[:, :, : self.cfg.vocab])
+            logits = np.asarray(logits[:, : self.cfg.vocab])
         with obs_mod.span("session.sample", self.tracer, **args):
             for i, entry in enumerate(self.slot_entry):
                 if entry is None:
@@ -962,7 +962,7 @@ class Session:
                     del self.slot_pending[i][:counts[i]]
                     if self.slot_pending[i]:
                         continue  # still prefilling
-                self._emit(i, logits[i, counts[i] - 1], now)
+                self._emit(i, logits[i], now)
         return counts, granted, logits.nbytes
 
     def _emit(self, i: int, logits_i: np.ndarray, now: float):

@@ -11,8 +11,10 @@ Mixed prefill+decode batches fall out of the per-slot ``n_tok`` vector:
 a prefilling slot carries up to C prompt tokens, a decoding slot carries
 1 (its next token, sampled host-side from the previous step's logits),
 an idle slot carries 0 — padding positions are redirected to the garbage
-page by ``update(valid=...)`` and their logits ignored, so one fixed
-[B, C] shape serves every step and the step jits once per (cfg, C).
+page by ``update(valid=...)``, so one fixed [B, C] shape serves every
+step and the step jits once per (cfg, C).  Only each slot's last fed
+position is sampled, so only its hidden state is unembedded: the step
+returns one [Vpad] row per slot, as the decode step does.
 
 Within-chunk causality needs no extra machinery: all C tokens' K/V are
 written (in ONE vectorized scatter, `kvstore.update_chunk` — same
@@ -108,15 +110,14 @@ def _stack_prefill(cfg: ArchConfig, stacked: Dict, states, x, positions,
     return new_states, x
 
 
-def prefill_step(cfg: ArchConfig, params: Dict, state: Dict,
-                 tokens: jnp.ndarray, n_tok: jnp.ndarray,
-                 plan=None) -> Tuple[Dict, jnp.ndarray]:
-    """tokens [B, C], n_tok [B] (0 = idle slot) -> (state', logits
-    [B, C, Vpad]).  Slot i's tokens occupy absolute positions
-    ``state["pos"][i] .. +n_tok[i]-1``; the caller ensures those
-    positions' pages exist in the table and samples from
-    ``logits[i, n_tok[i]-1]``.  ``plan`` = serving ShardingPlan (the
-    chunk step stays token-identical under it — see tests/test_shard)."""
+def prefill_hidden(cfg: ArchConfig, params: Dict, state: Dict,
+                   tokens: jnp.ndarray, n_tok: jnp.ndarray,
+                   plan=None) -> Tuple[Dict, jnp.ndarray]:
+    """The layer stack over a chunk: tokens [B, C], n_tok [B] (0 = idle
+    slot) -> (state', hidden states [B, C, D] before the final norm).
+    Slot i's tokens occupy absolute positions ``state["pos"][i] ..
+    +n_tok[i]-1``; the caller ensures those positions' pages exist in
+    the table."""
     if not supports_chunked_prefill(cfg):
         raise ValueError(f"{cfg.name} ({cfg.family}) has per-token "
                          "recurrent state; chunked prefill unsupported")
@@ -135,7 +136,23 @@ def prefill_step(cfg: ArchConfig, params: Dict, state: Dict,
                                    x, positions, valid, table, plan=plan)
     new_state = {"layers": new_layers, "pos": state["pos"] + n_tok,
                  "page_table": table}
-    return new_state, serve_logits(cfg, params, x)
+    return new_state, x
+
+
+def prefill_step(cfg: ArchConfig, params: Dict, state: Dict,
+                 tokens: jnp.ndarray, n_tok: jnp.ndarray,
+                 plan=None) -> Tuple[Dict, jnp.ndarray]:
+    """tokens [B, C], n_tok [B] (0 = idle slot) -> (state', logits
+    [B, Vpad]): row i is the logits of slot i's last fed position
+    ``n_tok[i]-1``, the one its next token is sampled from (an idle
+    slot's row is position 0's, and is not read).  ``plan`` = serving
+    ShardingPlan (the chunk step stays token-identical under it — see
+    tests/test_shard)."""
+    new_state, x = prefill_hidden(cfg, params, state, tokens, n_tok,
+                                  plan=plan)
+    last = jnp.maximum(n_tok - 1, 0)[:, None, None]           # [B, 1, 1]
+    x = jnp.take_along_axis(x, last, axis=1)                  # [B, 1, D]
+    return new_state, serve_logits(cfg, params, x)[:, 0, :]
 
 
 # Compiled chunk steps keyed by (cfg, C): the step is backend-agnostic

@@ -93,7 +93,8 @@ def test_step_records_count_each_call(served):
     vocab = sess.cfg.vocab
     for r in recs:
         per_slot = sess.chunk if r["kind"] == "chunked" else 1
-        assert r["d2h_bytes"] == sess.slots * per_slot * vocab * 4
+        # both kinds read back one logits row per slot
+        assert r["d2h_bytes"] == sess.slots * vocab * 4
         assert r["sampled"] == len(r["emitted"]) <= r["active"]
         assert r["tokens"] >= r["active"] > 0
         assert r["h2d_bytes"] >= sess.slots * per_slot * 4
