@@ -58,6 +58,7 @@ from repro import sched as schd
 from repro.api import env
 from repro.api.registry import Executor, get_backend
 from repro.configs.base import ArchConfig
+from repro.models import moe as moe_mod
 
 # env knobs resolved ONCE at import via repro.api.env (traced code must
 # not read os.environ); per-session override via the kv_cache= /
@@ -142,6 +143,8 @@ class Session:
                  scheduler=None, plan=None, resil=None, obs=None):
         assert cfg.has_decode, "encoder archs don't serve autoregressively"
         from repro.models import model as M
+        if cfg.moe:
+            params = moe_mod.serving_params(params)
         self.cfg, self.params = cfg, params
         self.plan = plan
         self._param_sh = None
@@ -204,16 +207,17 @@ class Session:
             self._state_sh = plan.state_shardings(self.state)
             self.state = jax.device_put(self.state, self._state_sh)
             rep = plan.replicated()
+            # logits, and a MoE step's per-layer pair counts
+            outs = (self._state_sh, rep) + ((rep,) if cfg.moe else ())
             step = backend.make_decode_step(cfg, plan=plan)
             self._step = jax.jit(
                 step,
                 in_shardings=(self._param_sh, self._state_sh, rep),
-                out_shardings=(self._state_sh, rep),
-                donate_argnums=(1,))
+                out_shardings=outs, donate_argnums=(1,))
             self._prefill = schd.make_prefill_step(
                 cfg, self.chunk, plan=plan,
                 in_shardings=(self._param_sh, self._state_sh, rep, rep),
-                out_shardings=(self._state_sh, rep)) \
+                out_shardings=outs) \
                 if self.chunk > 1 else None
         else:
             self._state_sh = None
@@ -247,6 +251,11 @@ class Session:
         self._emitted: List[Tuple[int, int]] = []
         self._h2d = 0
         self._step_ann = None      # the open session.step annotation
+        if cfg.moe:
+            # (token, expert) pairs computed here, valid tokens fed to
+            # the expert layers, and model calls, each times MoE layers
+            self.stats.update({"moe_pairs_here": 0, "moe_tokens": 0,
+                               "moe_layer_calls": 0})
         if kv_cache == "paged":
             self.stats.update({"page_allocs": 0, "pages_in_use": 0,
                                "pages_peak": 0, "pages_reclaimed_swa": 0,
@@ -740,15 +749,24 @@ class Session:
             raise
         if not events:
             return 0
-        si, pi, pids = (jnp.asarray([e[n] for e in events], jnp.int32)
-                        for n in range(3))
+        # padded to the most one step can grant (a slot's tokens span at
+        # most (chunk - 1) // page_size + 2 pages) with writes past the
+        # table's rows and the pool's pages, which are dropped: the eager
+        # scatters compile once, not once per count of grants (at 128
+        # slots hundreds of counts, some first met inside a window)
+        size = self.slots * ((self.chunk - 1) // self.page_size + 2)
+        pad = np.asarray([(self.slots, 0, self.alloc.n_pages)]
+                         * (size - len(events)), np.int32).reshape(-1, 3)
+        rows = np.concatenate([np.asarray(events, np.int32), pad])
+        si, pi, pids = (jnp.asarray(rows[:, n]) for n in range(3))
         self._h2d += si.nbytes + pi.nbytes + pids.nbytes
         self.state["page_table"] = \
-            self.state["page_table"].at[si, pi].set(pids)
+            self.state["page_table"].at[si, pi].set(pids, mode="drop")
         kv = self.state["layers"]["kv"]
         if kv.k_scale is not None:
-            kv = kv._replace(k_scale=kv.k_scale.at[:, pids].set(0.0),
-                             v_scale=kv.v_scale.at[:, pids].set(0.0))
+            kv = kv._replace(
+                k_scale=kv.k_scale.at[:, pids].set(0.0, mode="drop"),
+                v_scale=kv.v_scale.at[:, pids].set(0.0, mode="drop"))
             layers = dict(self.state["layers"])
             layers["kv"] = kv
             self.state["layers"] = layers
@@ -896,7 +914,8 @@ class Session:
             fed = jnp.asarray(tokens)
             self._h2d += tokens.nbytes
         with obs_mod.span("session.dispatch", self.tracer, **args):
-            self.state, logits = self._step(self.params, self.state, fed)
+            self.state, logits, *pairs = self._step(self.params,
+                                                    self.state, fed)
         self.stats["steps"] += 1
         self.tracer.span("step.decode", tick=self.tick, role=self.role,
                          active=sum(1 for c in counts if c),
@@ -907,7 +926,7 @@ class Session:
                 if entry is not None:
                     self.slot_pos[i] += 1
         with obs_mod.span("session.readback", self.tracer, **args):
-            logits = np.asarray(logits[:, : self.cfg.vocab])
+            logits, d2h = self._read_back(logits, pairs, sum(counts))
         with obs_mod.span("session.sample", self.tracer, **args):
             for i, entry in enumerate(self.slot_entry):
                 if entry is None:
@@ -917,7 +936,22 @@ class Session:
                     if self.slot_pending[i]:
                         continue  # still prefilling
                 self._emit(i, logits[i], now)
-        return counts, granted, logits.nbytes
+        return counts, granted, d2h
+
+    def _read_back(self, logits, pairs, tokens: int):
+        """The step's logits [B, vocab] on the host, and their bytes.  A
+        MoE step's per-layer pair counts (``pairs``: [] or [[L]]) come
+        back in the same transfer and feed the ``moe_*`` counters."""
+        if not pairs:
+            out = np.asarray(logits[:, : self.cfg.vocab])
+            return out, out.nbytes
+        out, per_layer = jax.device_get((logits[:, : self.cfg.vocab],
+                                         pairs[0]))
+        n_moe = len(per_layer)
+        self.stats["moe_pairs_here"] += int(per_layer.sum())
+        self.stats["moe_tokens"] += tokens * n_moe
+        self.stats["moe_layer_calls"] += n_moe
+        return out, out.nbytes + per_layer.nbytes
 
     def _advance_chunked(self, args: dict) -> Tuple[List[int], int, int]:
         """Mixed prefill+decode step: up to ``chunk`` prompt tokens per
@@ -942,8 +976,8 @@ class Session:
             fed = (jnp.asarray(tokens), jnp.asarray(n_tok))
             self._h2d += tokens.nbytes + n_tok.nbytes
         with obs_mod.span("session.dispatch", self.tracer, **args):
-            self.state, logits = self._prefill(self.params, self.state,
-                                               *fed)
+            self.state, logits, *pairs = self._prefill(self.params,
+                                                       self.state, *fed)
         self.stats["steps"] += 1
         self.tracer.span("step.prefill", tick=self.tick, role=self.role,
                          active=sum(1 for c in counts if c),
@@ -953,7 +987,7 @@ class Session:
             if entry is not None:
                 self.slot_pos[i] += counts[i]
         with obs_mod.span("session.readback", self.tracer, **args):
-            logits = np.asarray(logits[:, : self.cfg.vocab])
+            logits, d2h = self._read_back(logits, pairs, sum(counts))
         with obs_mod.span("session.sample", self.tracer, **args):
             for i, entry in enumerate(self.slot_entry):
                 if entry is None:
@@ -963,7 +997,7 @@ class Session:
                     if self.slot_pending[i]:
                         continue  # still prefilling
                 self._emit(i, logits[i], now)
-        return counts, granted, logits.nbytes
+        return counts, granted, d2h
 
     def _emit(self, i: int, logits_i: np.ndarray, now: float):
         """Sample the next token for slot ``i`` from this step's logits;

@@ -15,10 +15,29 @@ _REGISTRY = {}
 
 @dataclasses.dataclass(frozen=True)
 class MoECfg:
+    """A routed expert layer.  The router scores all ``n_experts``; this
+    chip holds and computes ``held`` of them (None: all), as one chip of
+    an expert-parallel deployment does."""
     n_experts: int
     top_k: int
-    group_size: int = 1024
-    capacity_factor: float = 1.25
+    d_expert: int                            # one expert's SwiGLU width
+    held: Optional[Tuple[int, ...]] = None
+
+    @property
+    def held_experts(self) -> Tuple[int, ...]:
+        return tuple(range(self.n_experts)) if self.held is None \
+            else self.held
+
+
+@dataclasses.dataclass(frozen=True)
+class YaRN:
+    """YaRN rotary scaling of the full-attention layers (HF
+    ``rope_type: yarn``); windowed layers keep plain RoPE."""
+    factor: float
+    original_max_position: int
+    beta_fast: float
+    beta_slow: float
+    attention_factor: float
 
 
 @dataclasses.dataclass(frozen=True)
@@ -34,6 +53,7 @@ class ArchConfig:
     d_head: Optional[int] = None   # default d_model // n_heads
     qkv_bias: bool = False
     rope_theta: Optional[float] = 10000.0
+    yarn: Optional[YaRN] = None    # full-attention layers' RoPE scaling
     window: int = -1               # SWA width for local layers (-1 = full)
     local_global_period: int = 0   # gemma2: every k-th layer is global
     full_attn_layers: Tuple[int, ...] = ()  # hymba: these layers are global
@@ -102,7 +122,8 @@ class ArchConfig:
         else:
             attn = d * h * dh + 2 * d * hkv * dh + h * dh * d
             if self.moe:
-                ffn = self.moe.n_experts * 3 * d * f + d * self.moe.n_experts
+                ffn = len(self.moe.held_experts) * 3 * d \
+                    * self.moe.d_expert + d * self.moe.n_experts
             else:
                 ffn = (3 if self.gated_mlp else 2) * d * f
             per = attn + ffn
@@ -117,7 +138,7 @@ class ArchConfig:
         d, f, L = self.d_model, self.d_ff, self.n_layers
         dh, h, hkv = self.head_dim, self.n_heads, self.n_kv
         attn = d * h * dh + 2 * d * hkv * dh + h * dh * d
-        ffn = self.moe.top_k * 3 * d * f
+        ffn = self.moe.top_k * 3 * d * self.moe.d_expert
         emb = self.vocab_padded * d * (1 if self.tie_embeddings else 2)
         return emb + L * (attn + ffn)
 
@@ -153,8 +174,7 @@ def reduced(cfg: ArchConfig, n_layers: int = 2, d_model: int = 128,
                                if i < n_layers))
     if cfg.moe:
         kw["moe"] = MoECfg(n_experts=min(cfg.moe.n_experts, 4),
-                           top_k=min(cfg.moe.top_k, 2), group_size=64,
-                           capacity_factor=2.0)
+                           top_k=min(cfg.moe.top_k, 2), d_expert=d_ff)
     if cfg.window > 0:
         kw["window"] = 32
     return dataclasses.replace(cfg, **kw)

@@ -48,7 +48,8 @@ def _merge_heads(x):
     return x.transpose(0, 2, 1, 3).reshape(b, t, h * d)
 
 
-def _qkv(p, x, n_heads, n_kv, d_head, positions, theta, plan=None):
+def _qkv(p, x, n_heads, n_kv, d_head, positions, theta, plan=None,
+         rope_table=None):
     q = dense(x, p["wq"], p.get("bq"), plan=plan)
     k = dense(x, p["wk"], p.get("bk"), plan=plan)
     v = dense(x, p["wv"], p.get("bv"), plan=plan)
@@ -56,8 +57,8 @@ def _qkv(p, x, n_heads, n_kv, d_head, positions, theta, plan=None):
     k = _split_heads(k, n_kv, d_head)
     v = _split_heads(v, n_kv, d_head)
     if theta is not None:
-        q = rope(q, positions, theta)
-        k = rope(k, positions, theta)
+        q = rope(q, positions, theta, rope_table)
+        k = rope(k, positions, theta, rope_table)
     return q, k, v
 
 
@@ -125,10 +126,12 @@ def attn_apply(p, x, positions, *, n_heads: int, n_kv: int, d_head: int,
                window, causal: bool = True, cap: Optional[float] = None,
                theta: Optional[float] = 10000.0,
                scale: Optional[float] = None, impl: str = "einsum",
-               chunk: int = 512, unroll: bool = False):
-    """Training / prefill self-attention.  window: traced scalar, -1=full."""
+               chunk: int = 512, unroll: bool = False, rope_table=None):
+    """Training / prefill self-attention.  window: traced scalar, -1=full;
+    ``rope_table``: this layer's RoPE data (layers.rope_tables)."""
     scale = (d_head ** -0.5) if scale is None else scale
-    q, k, v = _qkv(p, x, n_heads, n_kv, d_head, positions, theta)
+    q, k, v = _qkv(p, x, n_heads, n_kv, d_head, positions, theta,
+                   rope_table=rope_table)
     t = x.shape[1]
     if impl == "flash":
         win = None if (isinstance(window, int) and window < 0) else int(window)
@@ -191,11 +194,11 @@ def attn_decode(p, cache: kvc.KVCache, x, cur_pos, *, n_heads: int,
                 n_kv: int, d_head: int, window, ring: bool = False,
                 cap: Optional[float] = None,
                 theta: Optional[float] = 10000.0,
-                scale: Optional[float] = None, plan=None):
+                scale: Optional[float] = None, plan=None, rope_table=None):
     """One-token decode. x [B,1,D], cur_pos [B] absolute position."""
     scale = (d_head ** -0.5) if scale is None else scale
     q, k, v = _qkv(p, x, n_heads, n_kv, d_head, cur_pos[:, None], theta,
-                   plan=plan)
+                   plan=plan, rope_table=rope_table)
     cache, o = decode_attend(cache, q, k, v, cur_pos, window=window,
                              ring=ring, cap=cap, scale=scale)
     return cache, dense(_merge_heads(o.astype(COMPUTE_DTYPE)), p["wo"],
@@ -206,7 +209,8 @@ def attn_decode_paged(p, pool: kvs.PagedKV, table, x, cur_pos, *,
                       n_heads: int, n_kv: int, d_head: int, window,
                       cap: Optional[float] = None,
                       theta: Optional[float] = 10000.0,
-                      scale: Optional[float] = None, plan=None):
+                      scale: Optional[float] = None, plan=None,
+                      rope_table=None):
     """One-token decode against the paged KV pool (cache="paged" route).
 
     The current token's k/v are quantized into their page first, then the
@@ -219,7 +223,7 @@ def attn_decode_paged(p, pool: kvs.PagedKV, table, x, cur_pos, *,
     independent, so mesh output is bit-identical to single-device)."""
     scale = (d_head ** -0.5) if scale is None else scale
     q, k, v = _qkv(p, x, n_heads, n_kv, d_head, cur_pos[:, None], theta,
-                   plan=plan)
+                   plan=plan, rope_table=rope_table)
     pool, o = decode_attend_paged(pool, table, q, k, v, cur_pos,
                                   window=window, cap=cap, scale=scale,
                                   plan=plan)

@@ -6,10 +6,12 @@ mixed-precision policy: params f32, matmul compute bf16, norms/softmax f32.
 """
 from __future__ import annotations
 
-from typing import Optional
+import math
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.api import dispatch as _dispatch
 from repro.api import env as _env
@@ -96,16 +98,65 @@ def layer_norm(x, params, eps: float = 1e-5):
 
 # ------------------------------------------------------------------ rotary
 def rope(x: jnp.ndarray, positions: jnp.ndarray,
-         theta: float = 10000.0) -> jnp.ndarray:
-    """Rotary embedding. x [B, H, T, D], positions [B, T] (absolute)."""
+         theta: float = 10000.0, table=None) -> jnp.ndarray:
+    """Rotary embedding. x [B, H, T, D], positions [B, T] (absolute).
+    ``table`` = (inverse frequencies [D/2], scale) of this layer, from
+    :func:`rope_tables`; None keeps plain RoPE at ``theta``."""
     d = x.shape[-1]
     half = d // 2
-    freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    if table is None:
+        freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    else:
+        freqs = table[0]
     ang = positions[:, None, :, None].astype(jnp.float32) * freqs  # [B,1,T,half]
     cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if table is not None:
+        cos, sin = cos * table[1], sin * table[1]
     x1, x2 = x[..., :half].astype(jnp.float32), x[..., half:].astype(jnp.float32)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)
+
+
+def yarn_correction_range(dim: int, theta: float, yarn) -> Tuple[int, int]:
+    """The dims between which YaRN ramps from extrapolation to
+    interpolation (HF ``find_correction_range``, truncated)."""
+    def corr(rot):
+        return dim * math.log(yarn.original_max_position
+                              / (rot * 2 * math.pi)) / (2 * math.log(theta))
+    return (max(math.floor(corr(yarn.beta_fast)), 0),
+            min(math.ceil(corr(yarn.beta_slow)), dim - 1))
+
+
+def yarn_inv_freq(dim: int, theta: float, yarn) -> np.ndarray:
+    """YaRN inverse frequencies [dim/2] (HF ``_compute_yarn_parameters``):
+    interpolation ``1/(factor·θ^(2i/dim))`` and extrapolation
+    ``1/θ^(2i/dim)`` blended by a linear ramp over the correction
+    range."""
+    pos = theta ** (np.arange(0, dim, 2, dtype=np.float32) / dim)
+    extra, inter = 1.0 / pos, 1.0 / (yarn.factor * pos)
+    low, high = yarn_correction_range(dim, theta, yarn)
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float32) - low)
+                   / max(high - low, 1e-3), 0, 1)
+    keep = 1.0 - ramp                         # extrapolation's share
+    return (inter * (1 - keep) + extra * keep).astype(np.float32)
+
+
+def rope_tables(cfg):
+    """Per-layer RoPE data for the layer scan: (inverse frequencies
+    [L, D/2], cos/sin scale [L]) where layer kinds rotate differently
+    (YaRN on full-attention layers, plain RoPE on windowed ones), else
+    None: every layer rotates at ``rope_theta`` alone."""
+    if cfg.yarn is None or cfg.rope_theta is None:
+        return None
+    dh = cfg.head_dim
+    plain = cfg.rope_theta ** (-np.arange(0, dh // 2, dtype=np.float32)
+                               / (dh // 2))
+    yarn = yarn_inv_freq(dh, cfg.rope_theta, cfg.yarn)
+    full = [w < 0 for w in cfg.layer_windows()]
+    return (jnp.asarray(np.stack([yarn if f else plain for f in full]),
+                        jnp.float32),
+            jnp.asarray([cfg.yarn.attention_factor if f else 1.0
+                         for f in full], jnp.float32))
 
 
 # -------------------------------------------------------------------- MLPs

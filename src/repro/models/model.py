@@ -66,7 +66,7 @@ def _layer_specs(cfg: ArchConfig, mesh_shape: Dict[str, int]) -> Dict:
         sp["ln1p"] = dict(norm)
         sp["ln2p"] = dict(norm)
     if cfg.moe:
-        ep = cfg.moe.n_experts % mesh_shape.get(tp, 1) == 0
+        ep = len(cfg.moe.held_experts) % mesh_shape.get(tp, 1) == 0
         if ep:
             sp["moe"] = {"router": P(), "gate": P(tp, fsdp, None),
                          "up": P(tp, fsdp, None), "down": P(tp, None, fsdp)}
@@ -269,20 +269,24 @@ def init_decode_state(cfg: ArchConfig, batch: int, max_len: int,
 
 def decode_step(cfg: ArchConfig, params: Dict, state: Dict,
                 tokens: jnp.ndarray, unroll: bool = False,
-                plan=None) -> Tuple[Dict, jnp.ndarray]:
-    """tokens [B] -> (state', logits [B, Vpad]).  ``plan`` = the serving
-    ShardingPlan threaded down to every projection (None = replicated)."""
+                plan=None) -> Tuple:
+    """tokens [B] -> (state', logits [B, Vpad]), and for a MoE
+    configuration also the (token, expert) pairs each layer computed
+    here ([L] int32).  ``plan`` = the serving ShardingPlan threaded down
+    to every projection (None = replicated)."""
     x = embed(tokens[:, None], params["embed"])
     if cfg.embed_scale:
         x = x * jnp.asarray(cfg.d_model ** 0.5, COMPUTE_DTYPE)
     table = state.get("page_table")        # paged route (static branch)
-    new_layers, x = tfm.stack_decode(cfg, params["layers"], state["layers"],
-                                     x, state["pos"], unroll=unroll,
-                                     page_table=table, plan=plan)
+    new_layers, x, pairs = tfm.stack_decode(
+        cfg, params["layers"], state["layers"], x, state["pos"],
+        unroll=unroll, page_table=table, plan=plan)
     new_state = {"layers": new_layers, "pos": state["pos"] + 1}
     if table is not None:
         new_state["page_table"] = table
-    return new_state, serve_logits(cfg, params, x)[:, 0, :]
+    logits = serve_logits(cfg, params, x)[:, 0, :]
+    return (new_state, logits) if pairs is None \
+        else (new_state, logits, pairs)
 
 
 @jax.named_scope("logits")

@@ -1,87 +1,105 @@
-"""Mixture-of-Experts FFN (mixtral 8e/top-2, dbrx 16e/top-4).
+"""Mixture-of-Experts FFN (mellum2 64e/top-8, dbrx 16e/top-4, mixtral
+8e/top-2): a dropless routed layer over the experts this chip holds.
 
-GShard/Switch-style capacity-bounded dispatch in einsum form: tokens are
-grouped, each group dispatches at most ``capacity`` tokens per expert via
-one-hot tensors, and the expert FFNs run as batched einsums over stacked
-expert weights [E, ...].  Under pjit the expert dimension shards over the
-``model`` mesh axis when divisible (true EP — dbrx 16e on 16-way model
-axis), else experts replicate and the FFN shards internally (mixtral 8e).
-The overflow-dropped-token fraction and the Switch load-balancing aux loss
-are returned for logging/optimization.
+The router scores every expert (f32, softmax, top-k renormalised over
+the k chosen); the (token, expert) pairs whose expert is held here are
+sorted by expert and run as one grouped matmul per projection
+(``jax.lax.ragged_dot``: gate, up, SiLU, down), then weighted and summed
+back onto their tokens.  There is no capacity and nothing is dropped, so
+a token's output never depends on the other rows of its step.  Pairs
+whose expert is held elsewhere contribute nothing here: that is this
+chip's share of an expert-parallel deployment (``MoECfg.held``), and the
+shares of all chips add up to the whole layer.
 
-This is also where AIDA's sparsity story meets MoE: expert FFN weight
-matrices are exactly the sparse-FC serving surface (see core/sparse_fc).
+Expert stacks are initialised as f32 masters (training updates them);
+serving holds them in bf16, the precision the matmuls read them in
+(``serving_params``), so a decode step casts nothing.  The Switch
+load-balancing aux loss (training) is computed from the router
+probabilities.
 """
 from __future__ import annotations
 
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
+from repro.configs.base import MoECfg
 from repro.models.layers import COMPUTE_DTYPE, dense_init
 
 
-def moe_init(key, d: int, f: int, n_experts: int):
+def moe_init(key, d: int, cfg: MoECfg):
     ks = jax.random.split(key, 4)
-    return {
-        "router": dense_init(ks[0], d, n_experts),
-        "gate": jax.random.normal(ks[1], (n_experts, d, f), jnp.float32)
-        * (d ** -0.5),
-        "up": jax.random.normal(ks[2], (n_experts, d, f), jnp.float32)
-        * (d ** -0.5),
-        "down": jax.random.normal(ks[3], (n_experts, f, d), jnp.float32)
-        * (f ** -0.5),
-    }
+    h, f = len(cfg.held_experts), cfg.d_expert
+
+    def stack(k, d_in, d_out):
+        return jax.random.normal(k, (h, d_in, d_out), jnp.float32) \
+            * d_in ** -0.5
+
+    return {"router": dense_init(ks[0], d, cfg.n_experts),
+            "gate": stack(ks[1], d, f), "up": stack(ks[2], d, f),
+            "down": stack(ks[3], f, d)}
 
 
-def moe_apply(p, x, *, n_experts: int, top_k: int, group_size: int = 1024,
-              capacity_factor: float = 1.25) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """x [B, T, D] -> (y [B, T, D], aux_loss scalar)."""
+def serving_params(params):
+    """``params`` with every layer's expert stacks in bf16, as serving
+    holds them (a no-op where they already are)."""
+    layers = dict(params["layers"])
+    layers["moe"] = {k: v if k == "router" else v.astype(COMPUTE_DTYPE)
+                     for k, v in layers["moe"].items()}
+    return {**params, "layers": layers}
+
+
+def moe_apply(p, x, cfg: MoECfg, valid: Optional[jnp.ndarray] = None
+              ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """x [B, T, D] -> (y [B, T, D], aux_loss scalar, pairs int32: the
+    (token, expert) pairs computed here).  ``valid`` [B, T] marks the
+    positions that hold a token (None: all); the others are neither
+    routed, computed nor counted, and their output is zero."""
     b, t, d = x.shape
-    tokens = x.reshape(b * t, d)
-    n_tok = b * t
-    gs = min(group_size, n_tok)
-    assert n_tok % gs == 0
-    groups = n_tok // gs
-    xg = tokens.reshape(groups, gs, d)
-    capacity = max(1, int(gs * top_k * capacity_factor / n_experts))
-
-    logits = jnp.einsum("gsd,de->gse", xg.astype(jnp.float32),
-                        p["router"].astype(jnp.float32))
-    probs = jax.nn.softmax(logits, axis=-1)                 # [g, s, e]
-    gate_vals, gate_idx = jax.lax.top_k(probs, top_k)       # [g, s, k]
-    gate_vals = gate_vals / jnp.maximum(
-        gate_vals.sum(-1, keepdims=True), 1e-9)
-
-    # Switch aux loss: frac-of-tokens × frac-of-probability per expert
-    me = probs.mean(axis=(0, 1))
-    ce_mask = jax.nn.one_hot(gate_idx[..., 0], n_experts).mean(axis=(0, 1))
-    aux = n_experts * jnp.sum(me * ce_mask)
-
-    # position of each (token, k) within its expert's capacity buffer
-    sel = jax.nn.one_hot(gate_idx, n_experts, dtype=jnp.int32)  # [g,s,k,e]
-    flat_sel = sel.reshape(groups, gs * top_k, n_experts)
-    pos_in_e = jnp.cumsum(flat_sel, axis=1) * flat_sel - 1      # [g, s*k, e]
-    pos_in_e = pos_in_e.reshape(groups, gs, top_k, n_experts)
-    keep = (pos_in_e >= 0) & (pos_in_e < capacity)
-
-    # dispatch / combine tensors [g, s, e, c]
-    pos_oh = jax.nn.one_hot(jnp.clip(pos_in_e, 0, capacity - 1), capacity,
-                            dtype=jnp.float32) * keep[..., None]
-    dispatch = pos_oh.sum(axis=2)                               # [g,s,e,c]
-    combine = (pos_oh * gate_vals[..., None, None]).sum(axis=2)
-
-    ein = xg.astype(COMPUTE_DTYPE)
-    expert_in = jnp.einsum("gsec,gsd->gecd", dispatch.astype(COMPUTE_DTYPE),
-                           ein)                                 # [g,e,c,d]
-    gate_h = jnp.einsum("gecd,edf->gecf", expert_in,
-                        p["gate"].astype(COMPUTE_DTYPE))
-    up_h = jnp.einsum("gecd,edf->gecf", expert_in,
-                      p["up"].astype(COMPUTE_DTYPE))
-    h = jax.nn.silu(gate_h.astype(jnp.float32)).astype(COMPUTE_DTYPE) * up_h
-    expert_out = jnp.einsum("gecf,efd->gecd", h,
-                            p["down"].astype(COMPUTE_DTYPE))
-    y = jnp.einsum("gsec,gecd->gsd", combine.astype(COMPUTE_DTYPE),
-                   expert_out)
-    return y.reshape(b, t, d), aux.astype(jnp.float32)
+    n, k = b * t, cfg.top_k
+    held = cfg.held_experts
+    xs = x.reshape(n, d).astype(COMPUTE_DTYPE)
+    ok = jnp.ones((n,), bool) if valid is None else valid.reshape(n)
+    with jax.named_scope("moe.route"):
+        logits = jnp.matmul(xs.astype(jnp.float32),
+                            p["router"].astype(jnp.float32),
+                            precision=jax.lax.Precision.HIGHEST)
+        probs = jax.nn.softmax(logits, axis=-1)               # [n, E]
+        top_p, top_e = jax.lax.top_k(probs, k)                # [n, k]
+        top_p = top_p / top_p.sum(-1, keepdims=True)
+        # Switch aux loss over the valid tokens: share of tokens whose
+        # first choice is e times the mean probability of e
+        w_tok = ok.astype(jnp.float32) / jnp.maximum(ok.sum(), 1)
+        me = (probs * w_tok[:, None]).sum(0)
+        ce = (jax.nn.one_hot(top_e[:, 0], cfg.n_experts)
+              * w_tok[:, None]).sum(0)
+        aux = cfg.n_experts * jnp.sum(me * ce)
+        # local index of each pair's expert here (-1: held elsewhere)
+        local = np.full((cfg.n_experts,), -1, np.int32)
+        local[np.asarray(held)] = np.arange(len(held), dtype=np.int32)
+        pair_l = jnp.asarray(local)[top_e].reshape(n * k)
+        here = (pair_l >= 0) & jnp.repeat(ok, k)
+        # pairs here sorted by expert, the rest after them
+        order = jnp.argsort(jnp.where(here, pair_l, len(held)),
+                            stable=True)
+        sizes = jnp.zeros((len(held),), jnp.int32).at[
+            jnp.where(here, pair_l, 0)].add(here.astype(jnp.int32))
+        rows = xs[order // k]                                 # [n*k, D]
+    with jax.named_scope("moe.experts"):
+        def gmm(a, w):
+            return jax.lax.ragged_dot(a, w.astype(COMPUTE_DTYPE), sizes,
+                                      preferred_element_type=jnp.float32)
+        hid = (jax.nn.silu(gmm(rows, p["gate"]))
+               * gmm(rows, p["up"])).astype(COMPUTE_DTYPE)
+        out = gmm(hid, p["down"])                             # [n*k, D]
+    with jax.named_scope("moe.combine"):
+        # rows past the groups are not computed: select, never multiply
+        inv = jnp.argsort(order)
+        here_s = here[order]
+        out = jnp.where(here_s[:, None], out, 0.0)[inv].reshape(n, k, d)
+        wk = jnp.where(here.reshape(n, k), top_p, 0.0)
+        y = (out * wk[..., None]).sum(1)
+    return (y.reshape(b, t, d).astype(COMPUTE_DTYPE), aux,
+            here.sum(dtype=jnp.int32))

@@ -20,7 +20,8 @@ from repro.models import kvcache as kvc
 from repro.models import moe as moe_mod
 from repro.models import ssm
 from repro.models.layers import (COMPUTE_DTYPE, layer_norm, layer_norm_init,
-                                 mlp, mlp_init, rms_norm, rms_norm_init)
+                                 mlp, mlp_init, rms_norm, rms_norm_init,
+                                 rope_tables)
 
 
 def _norm(cfg: ArchConfig):
@@ -29,6 +30,24 @@ def _norm(cfg: ArchConfig):
 
 def _norm_init(cfg: ArchConfig, d: int):
     return rms_norm_init(d) if cfg.norm == "rms" else layer_norm_init(d)
+
+
+def layer_xs(cfg: ArchConfig) -> Dict:
+    """What varies from layer to layer, scanned beside the stacked
+    params: the attention window (-1 = full) and, where layer kinds
+    rotate differently, the RoPE table (None otherwise)."""
+    return {"window": jnp.asarray(cfg.layer_windows(), jnp.int32),
+            "rope": rope_tables(cfg)}
+
+
+def ffn(cfg: ArchConfig, p: Dict, y, plan=None, valid=None):
+    """The block's feed-forward: dense MLP or the routed expert layer.
+    Returns (out, aux loss, pairs computed here or None for a dense
+    MLP); ``valid`` [B, T] marks real tokens (MoE routes only those)."""
+    if cfg.moe:
+        return moe_mod.moe_apply(p["moe"], y, cfg.moe, valid)
+    return (mlp(y, p["mlp"], cfg.act, plan=plan),
+            jnp.zeros((), jnp.float32), None)
 
 
 # -------------------------------------------------------------- layer init
@@ -46,7 +65,7 @@ def layer_init(cfg: ArchConfig, key) -> Dict:
         p["ln1p"] = _norm_init(cfg, d)
         p["ln2p"] = _norm_init(cfg, d)
     if cfg.moe:
-        p["moe"] = moe_mod.moe_init(ks[1], d, f, cfg.moe.n_experts)
+        p["moe"] = moe_mod.moe_init(ks[1], d, cfg.moe)
     else:
         p["mlp"] = mlp_init(ks[1], d, f, cfg.gated_mlp, cfg.act)
     if cfg.family == "hymba":
@@ -69,8 +88,8 @@ def _attn_kwargs(cfg: ArchConfig):
 
 
 def block_forward(cfg: ArchConfig, p: Dict, x, positions, window,
-                  attn_impl: str = "einsum",
-                  unroll: bool = False) -> Tuple[jnp.ndarray, jnp.ndarray]:
+                  attn_impl: str = "einsum", unroll: bool = False,
+                  rope=None) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """One layer, training/prefill. Returns (x, aux_loss)."""
     nrm = _norm(cfg)
     aux = jnp.zeros((), jnp.float32)
@@ -85,7 +104,8 @@ def block_forward(cfg: ArchConfig, p: Dict, x, positions, window,
 
     h = attn.attn_apply(p["attn"], nrm(x, p["ln1"]), positions,
                         window=window, causal=cfg.causal,
-                        impl=attn_impl, unroll=unroll, **_attn_kwargs(cfg))
+                        impl=attn_impl, unroll=unroll, rope_table=rope,
+                        **_attn_kwargs(cfg))
     if cfg.family == "hymba":
         hs = ssm.mamba_apply(p["mamba"], nrm(x, p["ln1"]),
                              state=cfg.ssm_state)
@@ -93,13 +113,7 @@ def block_forward(cfg: ArchConfig, p: Dict, x, positions, window,
     if cfg.post_norms:
         h = nrm(h, p["ln1p"])
     x = x + h
-    if cfg.moe:
-        h, aux = moe_mod.moe_apply(
-            p["moe"], nrm(x, p["ln2"]), n_experts=cfg.moe.n_experts,
-            top_k=cfg.moe.top_k, group_size=cfg.moe.group_size,
-            capacity_factor=cfg.moe.capacity_factor)
-    else:
-        h = mlp(nrm(x, p["ln2"]), p["mlp"], cfg.act)
+    h, aux, _ = ffn(cfg, p, nrm(x, p["ln2"]))
     if cfg.post_norms:
         h = nrm(h, p["ln2p"])
     return x + h, aux
@@ -113,13 +127,11 @@ def stack_forward(cfg: ArchConfig, stacked: Dict, x, positions,
     unroll=True inlines every layer (used by the roofline cost extraction:
     XLA cost_analysis counts a while-loop body ONCE, so the scanned form
     under-reports flops by ~n_layers)."""
-    windows = jnp.asarray(cfg.layer_windows(), jnp.int32)
-
     def body(carry, inp):
         xc, auxc = carry
-        p, win = inp
-        xo, aux = block_forward(cfg, p, xc, positions, win, attn_impl,
-                                unroll=unroll)
+        p, lx = inp
+        xo, aux = block_forward(cfg, p, xc, positions, lx["window"],
+                                attn_impl, unroll=unroll, rope=lx["rope"])
         return (xo, auxc + aux), None
 
     if remat == "full":
@@ -128,7 +140,7 @@ def stack_forward(cfg: ArchConfig, stacked: Dict, x, positions,
         body = jax.checkpoint(
             body, policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
     (x, aux), _ = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)),
-                               (stacked, windows),
+                               (stacked, layer_xs(cfg)),
                                unroll=cfg.n_layers if unroll else 1)
     return x, aux
 
@@ -182,11 +194,12 @@ def init_stack_state(cfg: ArchConfig, batch: int, slots_full: int,
 
 
 def block_decode(cfg: ArchConfig, p: Dict, st: Dict, x, cur_pos, window,
-                 page_table=None, plan=None):
-    """One layer, one token. x [B,1,D].  ``plan`` (shard.ShardingPlan)
-    threads through to every projection so compressed FC runs
-    shard-local tensor-parallel; None = the replicated single-device
-    path, byte-identical to before."""
+                 page_table=None, plan=None, rope=None):
+    """One layer, one token. x [B,1,D] -> (state', x', pairs computed
+    here or None).  ``plan`` (shard.ShardingPlan) threads through to
+    every projection so compressed FC runs shard-local tensor-parallel;
+    None = the replicated single-device path, byte-identical to
+    before."""
     nrm = _norm(cfg)
     if cfg.family == "rwkv6":
         tm_st = {"prev": st["tm_prev"], "S": st["S"]}
@@ -197,18 +210,19 @@ def block_decode(cfg: ArchConfig, p: Dict, st: Dict, x, cur_pos, window,
         cm_prev, h = ssm.rwkv6_channel_mix_decode(p["cm"], st["cm_prev"],
                                                   nrm(x, p["ln2"]))
         return {"tm_prev": tm_st["prev"], "cm_prev": cm_prev,
-                "S": tm_st["S"]}, x + h
+                "S": tm_st["S"]}, x + h, None
 
     if page_table is not None:
         cache, h = attn.attn_decode_paged(p["attn"], st["kv"], page_table,
                                           nrm(x, p["ln1"]), cur_pos,
                                           window=window, plan=plan,
+                                          rope_table=rope,
                                           **_attn_kwargs(cfg))
     else:
         cache, h = attn.attn_decode(p["attn"], st["kv"], nrm(x, p["ln1"]),
                                     cur_pos, window=window,
                                     ring=not _any_global(cfg), plan=plan,
-                                    **_attn_kwargs(cfg))
+                                    rope_table=rope, **_attn_kwargs(cfg))
     new_st = dict(st)
     new_st["kv"] = cache
     if cfg.family == "hymba":
@@ -219,28 +233,24 @@ def block_decode(cfg: ArchConfig, p: Dict, st: Dict, x, cur_pos, window,
     if cfg.post_norms:
         h = nrm(h, p["ln1p"])
     x = x + h
-    if cfg.moe:
-        h, _ = moe_mod.moe_apply(
-            p["moe"], nrm(x, p["ln2"]), n_experts=cfg.moe.n_experts,
-            top_k=cfg.moe.top_k, group_size=cfg.moe.group_size,
-            capacity_factor=cfg.moe.capacity_factor)
-    else:
-        h = mlp(nrm(x, p["ln2"]), p["mlp"], cfg.act, plan=plan)
+    h, _, pairs = ffn(cfg, p, nrm(x, p["ln2"]), plan=plan)
     if cfg.post_norms:
         h = nrm(h, p["ln2p"])
-    return new_st, x + h
+    return new_st, x + h, pairs
 
 
 def stack_decode(cfg: ArchConfig, stacked: Dict, states, x, cur_pos,
                  unroll: bool = False, page_table=None, plan=None):
-    windows = jnp.asarray(cfg.layer_windows(), jnp.int32)
-
+    """-> (states', x', per-layer pairs computed here [L] or None)."""
     def body(xc, inp):
-        p, st, win = inp
-        new_st, xo = block_decode(cfg, p, st, xc, cur_pos, win,
-                                  page_table=page_table, plan=plan)
-        return xo, new_st
+        p, st, lx = inp
+        new_st, xo, pairs = block_decode(cfg, p, st, xc, cur_pos,
+                                         lx["window"],
+                                         page_table=page_table, plan=plan,
+                                         rope=lx["rope"])
+        return xo, (new_st, pairs)
 
-    x, new_states = jax.lax.scan(body, x, (stacked, states, windows),
-                                 unroll=cfg.n_layers if unroll else 1)
-    return new_states, x
+    x, (new_states, pairs) = jax.lax.scan(
+        body, x, (stacked, states, layer_xs(cfg)),
+        unroll=cfg.n_layers if unroll else 1)
+    return new_states, x, pairs

@@ -40,10 +40,9 @@ import jax.numpy as jnp
 from repro import kvstore as kvs
 from repro.configs.base import ArchConfig
 from repro.models import attention as attn
-from repro.models import moe as moe_mod
-from repro.models.layers import COMPUTE_DTYPE, embed, mlp
+from repro.models.layers import COMPUTE_DTYPE, embed
 from repro.models.model import serve_logits
-from repro.models.transformer import _norm
+from repro.models.transformer import _norm, ffn, layer_xs
 
 
 def supports_chunked_prefill(cfg: ArchConfig) -> bool:
@@ -54,15 +53,16 @@ def supports_chunked_prefill(cfg: ArchConfig) -> bool:
 
 
 def _block_prefill(cfg: ArchConfig, p: Dict, st: Dict, x, positions,
-                   valid, window, table, plan=None):
+                   valid, window, table, plan=None, rope=None):
     """One layer over a [B, C, D] chunk: write C tokens' K/V into pages,
-    then attend all C queries over the (now-updated) page table."""
+    then attend all C queries over the (now-updated) page table.
+    Returns (state', x', pairs computed here or None)."""
     nrm = _norm(cfg)
     scale = (cfg.head_dim ** -0.5) if cfg.attn_scale is None \
         else cfg.attn_scale
     q, k, v = attn._qkv(p["attn"], nrm(x, p["ln1"]), cfg.n_heads,
                         cfg.n_kv, cfg.head_dim, positions, cfg.rope_theta,
-                        plan=plan)
+                        plan=plan, rope_table=rope)
     pool = st["kv"]
     pool = kvs.update_chunk(pool, table,
                             k.astype(jnp.float32), v.astype(jnp.float32),
@@ -84,37 +84,32 @@ def _block_prefill(cfg: ArchConfig, p: Dict, st: Dict, x, positions,
     if cfg.post_norms:
         h = nrm(h, p["ln1p"])
     x = x + h
-    if cfg.moe:
-        h, _ = moe_mod.moe_apply(
-            p["moe"], nrm(x, p["ln2"]), n_experts=cfg.moe.n_experts,
-            top_k=cfg.moe.top_k, group_size=cfg.moe.group_size,
-            capacity_factor=cfg.moe.capacity_factor)
-    else:
-        h = mlp(nrm(x, p["ln2"]), p["mlp"], cfg.act, plan=plan)
+    h, _, pairs = ffn(cfg, p, nrm(x, p["ln2"]), plan=plan, valid=valid)
     if cfg.post_norms:
         h = nrm(h, p["ln2p"])
-    return new_st, x + h
+    return new_st, x + h, pairs
 
 
 def _stack_prefill(cfg: ArchConfig, stacked: Dict, states, x, positions,
                    valid, table, plan=None):
-    windows = jnp.asarray(cfg.layer_windows(), jnp.int32)
-
     def body(xc, inp):
-        p, st, win = inp
-        new_st, xo = _block_prefill(cfg, p, st, xc, positions, valid, win,
-                                    table, plan=plan)
-        return xo, new_st
+        p, st, lx = inp
+        new_st, xo, pairs = _block_prefill(cfg, p, st, xc, positions,
+                                           valid, lx["window"], table,
+                                           plan=plan, rope=lx["rope"])
+        return xo, (new_st, pairs)
 
-    x, new_states = jax.lax.scan(body, x, (stacked, states, windows))
-    return new_states, x
+    x, (new_states, pairs) = jax.lax.scan(
+        body, x, (stacked, states, layer_xs(cfg)))
+    return new_states, x, pairs
 
 
 def prefill_hidden(cfg: ArchConfig, params: Dict, state: Dict,
                    tokens: jnp.ndarray, n_tok: jnp.ndarray,
-                   plan=None) -> Tuple[Dict, jnp.ndarray]:
+                   plan=None) -> Tuple:
     """The layer stack over a chunk: tokens [B, C], n_tok [B] (0 = idle
-    slot) -> (state', hidden states [B, C, D] before the final norm).
+    slot) -> (state', hidden states [B, C, D] before the final norm),
+    and for a MoE configuration the pairs each layer computed here [L].
     Slot i's tokens occupy absolute positions ``state["pos"][i] ..
     +n_tok[i]-1``; the caller ensures those positions' pages exist in
     the table."""
@@ -132,27 +127,31 @@ def prefill_hidden(cfg: ArchConfig, params: Dict, state: Dict,
     x = embed(tokens, params["embed"])
     if cfg.embed_scale:
         x = x * jnp.asarray(cfg.d_model ** 0.5, COMPUTE_DTYPE)
-    new_layers, x = _stack_prefill(cfg, params["layers"], state["layers"],
-                                   x, positions, valid, table, plan=plan)
+    new_layers, x, pairs = _stack_prefill(cfg, params["layers"],
+                                          state["layers"], x, positions,
+                                          valid, table, plan=plan)
     new_state = {"layers": new_layers, "pos": state["pos"] + n_tok,
                  "page_table": table}
-    return new_state, x
+    return (new_state, x) if pairs is None else (new_state, x, pairs)
 
 
 def prefill_step(cfg: ArchConfig, params: Dict, state: Dict,
                  tokens: jnp.ndarray, n_tok: jnp.ndarray,
-                 plan=None) -> Tuple[Dict, jnp.ndarray]:
+                 plan=None) -> Tuple:
     """tokens [B, C], n_tok [B] (0 = idle slot) -> (state', logits
     [B, Vpad]): row i is the logits of slot i's last fed position
     ``n_tok[i]-1``, the one its next token is sampled from (an idle
-    slot's row is position 0's, and is not read).  ``plan`` = serving
+    slot's row is position 0's, and is not read).  A MoE configuration
+    also returns the (token, expert) pairs each layer computed here
+    ([L] int32), as the decode step does.  ``plan`` = serving
     ShardingPlan (the chunk step stays token-identical under it — see
     tests/test_shard)."""
-    new_state, x = prefill_hidden(cfg, params, state, tokens, n_tok,
-                                  plan=plan)
+    new_state, x, *pairs = prefill_hidden(cfg, params, state, tokens,
+                                          n_tok, plan=plan)
     last = jnp.maximum(n_tok - 1, 0)[:, None, None]           # [B, 1, 1]
     x = jnp.take_along_axis(x, last, axis=1)                  # [B, 1, D]
-    return new_state, serve_logits(cfg, params, x)[:, 0, :]
+    logits = serve_logits(cfg, params, x)[:, 0, :]
+    return (new_state, logits, *pairs)
 
 
 # Compiled chunk steps keyed by (cfg, C): the step is backend-agnostic

@@ -124,3 +124,41 @@ def test_paged_attention_compiles(one_chip, kv_dtype, shape):
             pool, table, jax.ShapeDtypeStruct((BATCH, CHUNK), jnp.int32),
             win)
     assert "tpu_custom_call" in hlo
+
+
+# mellum2-12b-ep4: 32 query heads over 4 KV heads of 128, 128 slots
+GQA_HEADS, GQA_KV, GQA_HEAD, GQA_BATCH = 32, 4, 128, 128
+
+
+@pytest.mark.parametrize("shape", ["decode", "chunk"])
+def test_gqa_paged_attention_compiles(one_chip, shape):
+    npp = MAX_LEN // PAGE
+    pool = jax.eval_shape(lambda: kvs.init_pool(
+        1 + GQA_BATCH * npp, GQA_KV, PAGE, GQA_HEAD, kv_dtype="bf16"))
+    table = jax.ShapeDtypeStruct((GQA_BATCH, npp), jnp.int32)
+    win = jax.ShapeDtypeStruct((), jnp.int32)
+    c = 1 if shape == "decode" else CHUNK
+    hlo = _compile(
+        one_chip,
+        lambda q, p, t, pos, w: kvs.paged_attention_pallas_chunk(
+            q, p, t, pos, w, pb=2, qt=c, interpret=False),
+        jax.ShapeDtypeStruct((GQA_BATCH, GQA_HEADS, c, GQA_HEAD),
+                             jnp.float32),
+        pool, table, jax.ShapeDtypeStruct((GQA_BATCH, c), jnp.int32), win)
+    assert "tpu_custom_call" in hlo
+
+
+def test_expert_grouped_matmul_compiles(one_chip):
+    """The expert layer's grouped matmul (``jax.lax.ragged_dot``) over 16
+    held experts at mellum2 widths and a [128, 16] chunked step's pairs
+    lowers to the chip's grouped-matmul kernel."""
+    from repro.configs.base import MoECfg
+    from repro.models import moe
+    cfg = MoECfg(n_experts=64, top_k=8, d_expert=896, held=tuple(range(16)))
+    p = jax.eval_shape(lambda: moe.serving_params({"layers": {
+        "moe": moe.moe_init(jax.random.PRNGKey(0), 2304, cfg)}}
+    )["layers"]["moe"])
+    hlo = _compile(one_chip, lambda p, x, v: moe.moe_apply(p, x, cfg, v),
+                   p, jax.ShapeDtypeStruct((128, 16, 2304), jnp.bfloat16),
+                   jax.ShapeDtypeStruct((128, 16), jnp.bool_))
+    assert "ragged-dot" in hlo and "tpu_custom_call" in hlo
