@@ -422,8 +422,8 @@ class Engine:
             1 + np.arange(batch * npp).reshape(batch, npp), jnp.int32)
         t_paged = timeit(jax.jit(
             lambda pl, qq, kk, vv, p: attn.decode_attend_paged(
-                pl, table, qq, kk, vv, p, window=jnp.int32(-1),
-                scale=scale)[1]),
+                jax.tree.map(lambda a: a[None], pl), 0, table, qq, kk, vv,
+                p, window=jnp.int32(-1), scale=scale)[1]),
             pool, q, k, v, cur)
         # FC term: every compressed projection leaf, layer-0 view x L
         t_fc = 0.0

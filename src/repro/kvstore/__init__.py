@@ -20,16 +20,18 @@ from repro.kvstore.pool import (GARBAGE_PAGE, NO_PAGE, PagedKV,
                                 chunk_attention_mask,
                                 copy_pages, dense_kv_bytes_per_token,
                                 gather_kv, init_pool, init_table,
-                                kv_bytes_per_token, update, update_chunk)
+                                kv_bytes_per_token, layer_view, update,
+                                update_chunk, update_layer)
 
 __all__ = [
     "GARBAGE_PAGE", "NO_PAGE", "OutOfPages", "PageAllocator", "PagedKV",
     "chunk_attention_mask", "copy_pages",
     "dense_kv_bytes_per_token",
     "gather_kv", "init_pool", "init_table", "kv_bytes_per_token",
+    "layer_view",
     "npp_bucket", "paged_attention", "paged_attention_chunk",
     "paged_attention_pallas", "paged_attention_pallas_chunk",
     "paged_attention_xla", "paged_attention_xla_chunk",
     "reclaimable_prefix", "resolve_paged", "resolve_paged_chunk",
-    "update", "update_chunk",
+    "update", "update_chunk", "update_layer",
 ]

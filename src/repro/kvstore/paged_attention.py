@@ -64,35 +64,49 @@ def _softcap(s, cap: Optional[float]):
 def paged_attention_xla(q: jnp.ndarray, pool: PagedKV, table: jnp.ndarray,
                         cur_pos: jnp.ndarray, window, *,
                         scale: Optional[float] = None,
-                        cap: Optional[float] = None) -> jnp.ndarray:
+                        cap: Optional[float] = None,
+                        layer=None) -> jnp.ndarray:
     """Reference path: q [B, H, Dh] against the paged pool -> [B, H, Dh]
     f32 — the chunk reference with one query per sequence at
     ``cur_pos``."""
     o = paged_attention_xla_chunk(
         q[:, :, None], pool, table, jnp.asarray(cur_pos)[:, None], window,
-        scale=scale, cap=cap)
+        scale=scale, cap=cap, layer=layer)
     return o[:, :, 0]
 
 
 def paged_attention_xla_chunk(q: jnp.ndarray, pool: PagedKV,
                               table: jnp.ndarray, q_pos: jnp.ndarray,
                               window, *, scale: Optional[float] = None,
-                              cap: Optional[float] = None) -> jnp.ndarray:
+                              cap: Optional[float] = None,
+                              layer=None) -> jnp.ndarray:
     """Multi-query reference for the chunked-prefill step: q [B, H, C, Dh]
     at absolute positions ``q_pos`` [B, C] against the paged pool ->
     [B, H, C, Dh] f32.  GQA by grouping query heads (no k/v repeat),
     masks from table-index positions — mirrors models.attention._core
     over gathered pages.  Chunk tokens see each other through the pool
-    because their K/V are written before the chunk attends."""
+    because their K/V are written before the chunk attends.  With
+    ``layer``, ``pool`` is the stacked [L, n_pages, ...] pool and the
+    pages are gathered from its layer ``layer`` directly, so the layer
+    is never sliced out whole."""
     b, h, c, dh = q.shape
-    _, hkv, ps, _ = pool.k_pages.shape
+    hkv, ps, _ = pool.k_pages.shape[-3:]
     g = h // hkv
     npp = table.shape[1]
     scale = (dh ** -0.5) if scale is None else scale
     safe = jnp.maximum(table, poolmod.GARBAGE_PAGE)
+
+    def pages(a):                              # [B, P, ...] of the layer
+        if layer is None:
+            # jnp.take, not a[safe]: with a[safe] the decode step,
+            # compiled for a v5e at qwen1.5-0.5b widths, converts the
+            # gathered pages to f32 in memory before the scores
+            return jnp.take(a, safe, axis=0)
+        return a[layer, safe]
+
     with jax.named_scope("kv.read"):
-        k = jnp.take(pool.k_pages, safe, axis=0)   # [B, P, Hkv, ps, Dh]
-        v = jnp.take(pool.v_pages, safe, axis=0)
+        k = pages(pool.k_pages)                # [B, P, Hkv, ps, Dh]
+        v = pages(pool.v_pages)
     # unquantized pages mirror _core's mixed precision (bf16 operands,
     # f32 accumulate/softmax) so paged bf16 == full cache up to reduction
     # order; int8 pages contract in f32 (dequant headroom)
@@ -105,7 +119,7 @@ def paged_attention_xla_chunk(q: jnp.ndarray, pool: PagedKV,
                    preferred_element_type=jnp.float32) * scale
     if pool.quantized:
         with jax.named_scope("kv.read"):
-            ks = jnp.take(pool.k_scale, safe, axis=0)   # [B, P, Hkv]
+            ks = pages(pool.k_scale)                    # [B, P, Hkv]
         s = s * ks.transpose(0, 2, 1)[:, :, None, None, :, None]
     s = _softcap(s, cap)
     mask = poolmod.chunk_attention_mask(
@@ -116,7 +130,7 @@ def paged_attention_xla_chunk(q: jnp.ndarray, pool: PagedKV,
     p = p.reshape(b, hkv, g, c, npp, ps)
     if pool.quantized:
         with jax.named_scope("kv.read"):
-            vs = jnp.take(pool.v_scale, safe, axis=0)
+            vs = pages(pool.v_scale)
         p = p * vs.transpose(0, 2, 1)[:, :, None, None, :, None]
     o = jnp.einsum("bkgqpc,bpkcd->bkgqd", p.astype(cdt), v.astype(cdt),
                    preferred_element_type=jnp.float32)
@@ -320,7 +334,7 @@ def resolve_paged(batch: int, h: int, d_head: int, pool: PagedKV,
     kernel (same accumulation order, token-identical output)."""
     from repro.kernels import tune as _tune
     interp = interpret_mode(interpret)
-    hkv = pool.k_pages.shape[1]
+    hkv = pool.k_pages.shape[-3]
     choice = _tune.get(_tune.paged_key(hkv, h // hkv, d_head,
                                        pool.page_size, npp, batch,
                                        pool.quantized, interp))
@@ -336,7 +350,7 @@ def resolve_paged_chunk(batch: int, h: int, chunk: int, d_head: int,
     """Resolve the tuned chunk choice -> (impl, pb, qt, interpret)."""
     from repro.kernels import tune as _tune
     interp = interpret_mode(interpret)
-    hkv = pool.k_pages.shape[1]
+    hkv = pool.k_pages.shape[-3]
     choice = _tune.get(_tune.paged_chunk_key(hkv, h // hkv, d_head,
                                              pool.page_size, npp, batch,
                                              chunk, pool.quantized, interp))
@@ -352,11 +366,14 @@ def paged_attention(q, pool: PagedKV, table, cur_pos, window, *,
                     cap: Optional[float] = None,
                     impl: Optional[str] = None,
                     pb: Optional[int] = None,
-                    interpret: Optional[bool] = None) -> jnp.ndarray:
+                    interpret: Optional[bool] = None,
+                    layer=None) -> jnp.ndarray:
     """Autotuned entry point: Pallas kernel or the XLA gather reference
     per the kernels.tune winner for this (geometry, batch, backend).
     Pass ``impl``/``pb`` to pin a choice (the shard wrappers do, with the
-    globally-resolved one)."""
+    globally-resolved one).  With ``layer``, ``pool`` is the stacked
+    pool: the XLA path gathers that layer's pages from it, the Pallas
+    kernel takes the layer's view."""
     if impl is None:
         b, h, dh = q.shape
         impl, pb, interpret = resolve_paged(b, h, dh, pool,
@@ -365,7 +382,9 @@ def paged_attention(q, pool: PagedKV, table, cur_pos, window, *,
         interpret = interpret_mode(interpret)
     if impl == "xla":
         return paged_attention_xla(q, pool, table, cur_pos, window,
-                                   scale=scale, cap=cap)
+                                   scale=scale, cap=cap, layer=layer)
+    if layer is not None:
+        pool = poolmod.layer_view(pool, layer)
     return paged_attention_pallas(q, pool, table, cur_pos, window,
                                   scale=scale, cap=cap,
                                   pb=pb or 2, interpret=interpret)
@@ -378,11 +397,13 @@ def paged_attention_chunk(q, pool: PagedKV, table, q_pos, window, *,
                           impl: Optional[str] = None,
                           pb: Optional[int] = None,
                           qt: Optional[int] = None,
-                          interpret: Optional[bool] = None) -> jnp.ndarray:
+                          interpret: Optional[bool] = None,
+                          layer=None) -> jnp.ndarray:
     """Autotuned chunked-prefill entry point: q [B, H, C, Dh] at absolute
     positions ``q_pos`` [B, C] -> [B, H, C, Dh].  Dispatches between
     :func:`paged_attention_pallas_chunk` and the XLA gather reference per
-    the kernels.tune winner for this (geometry, batch, chunk, backend)."""
+    the kernels.tune winner for this (geometry, batch, chunk, backend);
+    ``layer`` as in :func:`paged_attention`."""
     if impl is None:
         b, h, c, dh = q.shape
         impl, pb, qt, interpret = resolve_paged_chunk(
@@ -391,7 +412,9 @@ def paged_attention_chunk(q, pool: PagedKV, table, q_pos, window, *,
         interpret = interpret_mode(interpret)
     if impl == "xla":
         return paged_attention_xla_chunk(q, pool, table, q_pos, window,
-                                         scale=scale, cap=cap)
+                                         scale=scale, cap=cap, layer=layer)
+    if layer is not None:
+        pool = poolmod.layer_view(pool, layer)
     return paged_attention_pallas_chunk(q, pool, table, q_pos, window,
                                         scale=scale, cap=cap, pb=pb or 2,
                                         qt=qt, interpret=interpret)

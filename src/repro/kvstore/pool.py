@@ -22,15 +22,22 @@ update (one page of traffic, ≤0.5 LSB added error per rescale).  Page 0
 is reserved as a garbage sink: unallocated table entries (-1) clamp to it
 so inactive batch slots can write unconditionally inside jit.
 
+The serving steps hold every layer's pool stacked, ``[L, n_pages, Hkv,
+page_size, Dh]``, and carry it through their layer scan: each layer
+writes whole pages (:func:`update_layer`), in place where the chip holds
+pages major, so no step copies the stack anew.
+
 Bytes per token (k+v): int8 pages cost ``2·Hkv·Dh + 8·Hkv/page_size``
 vs ``4·Hkv·Dh`` for the dense bf16 cache — ~0.50x at Dh=32, ps=16.
 """
 from __future__ import annotations
 
-from typing import NamedTuple, Optional, Tuple
+from typing import Callable, NamedTuple, Optional, Tuple, TypeVar
 
 import jax
 import jax.numpy as jnp
+
+_T = TypeVar("_T")
 
 #: table entry meaning "no page allocated here"
 NO_PAGE = -1
@@ -49,11 +56,11 @@ class PagedKV(NamedTuple):
 
     @property
     def page_size(self) -> int:
-        return int(self.k_pages.shape[2])
+        return int(self.k_pages.shape[-2])
 
     @property
     def n_pages(self) -> int:
-        return int(self.k_pages.shape[0])
+        return int(self.k_pages.shape[-4])
 
     @property
     def quantized(self) -> bool:
@@ -114,6 +121,53 @@ def _write_page_rescale(pages, scale, new, new_s, safe_page, slot):
     return pages, scale
 
 
+def _write_tokens(pool: PagedKV, table: jnp.ndarray, k_new: jnp.ndarray,
+                  v_new: jnp.ndarray, start: jnp.ndarray,
+                  valid: Optional[jnp.ndarray], layer=None) -> PagedKV:
+    """bf16 write of a run of tokens, k/v [B, Hkv, C, Dh] at positions
+    ``start[b] .. start[b] + C - 1`` (``valid`` [B, C] or None = all), a
+    whole page at a time: gather the pages the run reaches, merge its
+    tokens in, and scatter the pages back indexing the page axis alone
+    (``[layer, page]`` in a stacked [L, n_pages, ...] pool).  A scatter
+    that also indexed the slot axis would take the head axis as its
+    window, and XLA then lays the whole pool out again around it.
+
+    Every page other than the garbage page ends up as a per-token
+    scatter would leave it.  Invalid tokens and ``NO_PAGE`` entries go
+    to the garbage page, and so does a table index no valid token
+    reaches, so one row never names a page twice; positions past the
+    table write nothing.  Two rows never write the same real page: the
+    allocator gives each active slot its own, and shared prefix pages
+    are full and never written."""
+    b, hkv, c, dh = k_new.shape
+    ps = pool.page_size
+    npp = table.shape[1]
+    span = (c + ps - 2) // ps + 1              # pages C positions can reach
+    first = start // ps                                     # [B]
+    idx = first[:, None] + jnp.arange(span, dtype=jnp.int32)  # [B, span]
+    # the chunk token each cell of the reached pages would take
+    tok = (jnp.arange(span * ps, dtype=jnp.int32)[None]
+           - (start % ps)[:, None])                         # [B, span*ps]
+    lands = (tok >= 0) & (tok < c)
+    tok = jnp.clip(tok, 0, c - 1)
+    if valid is not None:
+        lands &= jnp.take_along_axis(valid, tok, axis=1)
+    lands = lands.reshape(b, span, ps)
+    reached = lands.any(axis=-1) & (idx < npp)
+    page = jnp.take_along_axis(table, jnp.minimum(idx, npp - 1), axis=1)
+    ids = jnp.maximum(jnp.where(reached, page, NO_PAGE), GARBAGE_PAGE)
+    at = ids if layer is None else (layer, ids)
+
+    def write(pages, new):
+        new = new.astype(pages.dtype).transpose(0, 2, 1, 3)  # [B, C, Hkv, Dh]
+        new = jnp.take_along_axis(new, tok[:, :, None, None], axis=1)
+        new = new.reshape(b, span, ps, hkv, dh).transpose(0, 1, 3, 2, 4)
+        merged = jnp.where(lands[:, :, None, :, None], new, pages[at])
+        return pages.at[at].set(merged)
+
+    return PagedKV(write(pool.k_pages, k_new), write(pool.v_pages, v_new))
+
+
 @jax.named_scope("kv.write")
 def update(pool: PagedKV, table: jnp.ndarray, k_new: jnp.ndarray,
            v_new: jnp.ndarray, cur_pos: jnp.ndarray,
@@ -131,7 +185,12 @@ def update(pool: PagedKV, table: jnp.ndarray, k_new: jnp.ndarray,
     covers the new token (the steady state — scales grow only a handful
     of times per page), the write is a plain scatter of fresh codes; only
     a genuine scale growth pays the gather-requantize-scatter round trip
-    (lax.cond, so the fast path skips the page traffic entirely)."""
+    (lax.cond, so the fast path skips the page traffic entirely).  bf16
+    mode writes the token's whole page (:func:`_write_tokens`)."""
+    if not pool.quantized:
+        return _write_tokens(pool, table, k_new[:, :, None],
+                             v_new[:, :, None], cur_pos,
+                             None if valid is None else valid[:, None])
     ps = pool.page_size
     npp = table.shape[1]
     pi = jnp.clip(cur_pos // ps, 0, npp - 1)
@@ -140,11 +199,6 @@ def update(pool: PagedKV, table: jnp.ndarray, k_new: jnp.ndarray,
     if valid is not None:
         page = jnp.where(valid, page, NO_PAGE)
     safe = jnp.maximum(page, GARBAGE_PAGE)                # -1 -> sink page
-    if not pool.quantized:
-        dt = pool.k_pages.dtype
-        kp = pool.k_pages.at[safe, :, slot].set(k_new.astype(dt))
-        vp = pool.v_pages.at[safe, :, slot].set(v_new.astype(dt))
-        return PagedKV(kp, vp)
     kf = k_new.astype(jnp.float32)
     vf = v_new.astype(jnp.float32)
     k_amax = jnp.max(jnp.abs(kf), axis=-1) / 127.0        # [B, Hkv]
@@ -184,9 +238,11 @@ def update_chunk(pool: PagedKV, table: jnp.ndarray, k_new: jnp.ndarray,
     scan of C single-token writes (the chunked-prefill hot path).
 
     ``valid`` [B, C] bool redirects padding tokens to the garbage sink
-    exactly like :func:`update`'s per-token flag.  bf16 pools are
-    bit-identical to the equivalent scan (same values land in the same
-    distinct (page, slot) cells).  int8 pools keep the two-speed
+    exactly like :func:`update`'s per-token flag.  bf16 pools take each
+    row's positions as one run from ``positions[:, 0]`` (the chunk step
+    feeds consecutive positions) and write the pages it reaches whole
+    (:func:`_write_tokens`), bit-identical to the equivalent scan on
+    every page but the garbage page.  int8 pools keep the two-speed
     semantics at chunk granularity: per-page scales grow to cover the
     chunk's max |amax| landing on each page (a segment-max scatter), and
     only a genuine growth pays the gather-requantize-scatter round trip
@@ -194,8 +250,10 @@ def update_chunk(pool: PagedKV, table: jnp.ndarray, k_new: jnp.ndarray,
     quantized directly against the final page scale, so a chunk write
     never pays the intra-chunk rescale random walk the scan did (error
     stays within the same ~1 LSB bound, from above)."""
+    if not pool.quantized:
+        return _write_tokens(pool, table, k_new, v_new, positions[:, 0],
+                             valid)
     ps = pool.page_size
-    b, c = positions.shape
     npp = table.shape[1]
     pi = jnp.clip(positions // ps, 0, npp - 1)            # [B, C]
     slot = positions % ps
@@ -206,11 +264,6 @@ def update_chunk(pool: PagedKV, table: jnp.ndarray, k_new: jnp.ndarray,
     # token-major layout: [B, C, Hkv, Dh] matches the scatter index shape
     kf = k_new.astype(jnp.float32).transpose(0, 2, 1, 3)
     vf = v_new.astype(jnp.float32).transpose(0, 2, 1, 3)
-    if not pool.quantized:
-        dt = pool.k_pages.dtype
-        kp = pool.k_pages.at[safe, :, slot].set(kf.astype(dt))
-        vp = pool.v_pages.at[safe, :, slot].set(vf.astype(dt))
-        return PagedKV(kp, vp)
     k_amax = jnp.max(jnp.abs(kf), axis=-1) / 127.0        # [B, C, Hkv]
     v_amax = jnp.max(jnp.abs(vf), axis=-1) / 127.0
     if valid is not None:
@@ -258,6 +311,84 @@ def update_chunk(pool: PagedKV, table: jnp.ndarray, k_new: jnp.ndarray,
         return PagedKV(kp, vp, new_ks_full, new_vs_full)
 
     return jax.lax.cond(grow, slow, fast, pool)
+
+
+def layer_view(pool: PagedKV, layer) -> PagedKV:
+    """Layer ``layer`` of a stacked [L, n_pages, ...] pool."""
+    return jax.tree.map(
+        lambda a: jax.lax.dynamic_index_in_dim(a, layer, keepdims=False),
+        pool)
+
+
+def _pages_minor_on_tpu(pool: PagedKV) -> bool:
+    """Whether a TPU holds this pool with its pages in the lanes: with a
+    head that is not a whole number of the 128 lanes, any order that
+    keeps a page's rows together pads each row to 128, so the chip's
+    default order puts the page axis minor-most, the order that pads
+    least.  No page can be gathered or scattered where it lies then."""
+    return pool.k_pages.shape[-1] % 128 != 0
+
+
+def _update_layer_sliced(pool: PagedKV, layer, table, k_new, v_new,
+                         positions, valid, attend):
+    """:func:`update_layer` on a slice: layer ``layer`` is sliced out,
+    written there (:func:`update` for one token, :func:`update_chunk` for
+    a chunk), read there and put back."""
+    with jax.named_scope("kv.write"):
+        view = layer_view(pool, layer)
+    if k_new.shape[2] == 1:
+        view = update(view, table, k_new[:, :, 0], v_new[:, :, 0],
+                      positions[:, 0], None if valid is None else valid[:, 0])
+    else:
+        view = update_chunk(view, table, k_new, v_new, positions, valid)
+    out = attend(view, None)
+    with jax.named_scope("kv.write"):
+        pool = jax.tree.map(
+            lambda a, v: jax.lax.dynamic_update_index_in_dim(a, v, layer, 0),
+            pool, view)
+    return pool, out
+
+
+def _update_layer_in_place(pool: PagedKV, layer, table, k_new, v_new,
+                           positions, valid, attend):
+    """:func:`update_layer` in place: whole bf16 pages written at
+    ``[layer, page]`` of the stack, and attention gathers the layer's
+    pages from the stack."""
+    with jax.named_scope("kv.write"):
+        pool = _write_tokens(pool, table, k_new, v_new, positions[:, 0],
+                             valid, layer)
+    return pool, attend(pool, layer)
+
+
+def update_layer(pool: PagedKV, layer, table: jnp.ndarray,
+                 k_new: jnp.ndarray, v_new: jnp.ndarray,
+                 positions: jnp.ndarray, valid: Optional[jnp.ndarray],
+                 attend: Callable[[PagedKV, Optional[jnp.ndarray]], _T]
+                 ) -> Tuple[PagedKV, _T]:
+    """Write a run of tokens, k/v [B, Hkv, C, Dh] at contiguous
+    positions [B, C] (C = 1 for a decode token), into layer ``layer`` of
+    the stacked pool [L, n_pages, Hkv, ps, Dh] that the serving steps
+    carry through their layer scan, then attend over that layer.
+    Returns (pool', ``attend(src, at)``): ``attend`` reads layer ``at``
+    of the stacked pool ``src``, or with ``at`` None the one layer's
+    pool ``src`` (``paged_attention(..., layer=at)``).
+
+    A bf16 pool held pages major is written in place
+    (:func:`_update_layer_in_place`), so no step copies a layer or the
+    stack.  Where a TPU holds the pool pages minor
+    (:func:`_pages_minor_on_tpu`; compiled for a v5e at qwen1.5-0.5b
+    widths, writing in place there lays the whole stack out pages major
+    and back, 6 copies of it a step), and for an int8 pool (two-speed
+    write unchanged), each layer is written on a slice
+    (:func:`_update_layer_sliced`)."""
+    args = (layer, table, k_new, v_new, positions, valid, attend)
+    if pool.quantized:
+        return _update_layer_sliced(pool, *args)
+    if not _pages_minor_on_tpu(pool):
+        return _update_layer_in_place(pool, *args)
+    return jax.lax.platform_dependent(
+        pool, tpu=lambda p: _update_layer_sliced(p, *args),
+        default=lambda p: _update_layer_in_place(p, *args))
 
 
 def gather_kv(pool: PagedKV, table: jnp.ndarray

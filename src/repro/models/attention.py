@@ -168,25 +168,33 @@ def decode_attend(cache: kvc.KVCache, q, k, v, cur_pos, *, window,
     return cache, o
 
 
-def decode_attend_paged(pool: kvs.PagedKV, table, q, k, v, cur_pos, *,
-                        window, cap: Optional[float] = None,
+def decode_attend_paged(pool: kvs.PagedKV, layer, table, q, k, v,
+                        cur_pos, *, window, cap: Optional[float] = None,
                         scale: float = 1.0, impl: Optional[str] = None,
                         plan=None):
-    """Paged counterpart of decode_attend: quantize-into-page update +
-    page-gather attention (q/k/v are [B, H(kv), 1, Dh] as from _qkv).
-    ``impl`` overrides the tuner's kernel choice; with a ``plan`` the
-    tuned kernel runs shard-local over the head axis via shard_map."""
-    pool = kvs.update(pool, table, k[:, :, 0].astype(jnp.float32),
-                      v[:, :, 0].astype(jnp.float32), cur_pos)
-    if impl is None and plan is not None and plan.tp > 1:
-        from repro.shard import paged_attention_sharded
-        o = paged_attention_sharded(plan, q[:, :, 0], pool, table, cur_pos,
-                                    jnp.asarray(window, jnp.int32),
-                                    scale=scale, cap=cap)
-    else:
-        o = kvs.paged_attention(q[:, :, 0], pool, table, cur_pos,
-                                jnp.asarray(window, jnp.int32),
-                                scale=scale, cap=cap, impl=impl)
+    """Paged counterpart of decode_attend: write the token into layer
+    ``layer`` of the stacked pool [L, n_pages, ...]
+    (kvstore.update_layer), then page-gather attention over that layer's
+    pages (q/k/v are [B, H(kv), 1, Dh] as from _qkv).  Returns the
+    stacked pool.  ``impl`` overrides the tuner's kernel choice; with a
+    ``plan`` the tuned kernel runs shard-local over the head axis via
+    shard_map."""
+    def attend(src, at):
+        if impl is None and plan is not None and plan.tp > 1:
+            from repro.shard import paged_attention_sharded
+            view = src if at is None else kvs.layer_view(src, at)
+            return paged_attention_sharded(plan, q[:, :, 0], view, table,
+                                           cur_pos,
+                                           jnp.asarray(window, jnp.int32),
+                                           scale=scale, cap=cap)
+        return kvs.paged_attention(q[:, :, 0], src, table, cur_pos,
+                                   jnp.asarray(window, jnp.int32),
+                                   scale=scale, cap=cap, impl=impl,
+                                   layer=at)
+
+    pool, o = kvs.update_layer(pool, layer, table, k.astype(jnp.float32),
+                               v.astype(jnp.float32), cur_pos[:, None],
+                               None, attend)
     return pool, o[:, :, None, :]
 
 
@@ -205,15 +213,16 @@ def attn_decode(p, cache: kvc.KVCache, x, cur_pos, *, n_heads: int,
                         plan=plan)
 
 
-def attn_decode_paged(p, pool: kvs.PagedKV, table, x, cur_pos, *,
+def attn_decode_paged(p, pool: kvs.PagedKV, layer, table, x, cur_pos, *,
                       n_heads: int, n_kv: int, d_head: int, window,
                       cap: Optional[float] = None,
                       theta: Optional[float] = 10000.0,
                       scale: Optional[float] = None, plan=None,
                       rope_table=None):
-    """One-token decode against the paged KV pool (cache="paged" route).
+    """One-token decode of layer ``layer`` against the stacked paged KV
+    pool (cache="paged" route); returns the stacked pool.
 
-    The current token's k/v are quantized into their page first, then the
+    The current token's k/v are written into its page first, then the
     paged-attention kernel attends over the sequence's page table — same
     write-then-attend semantics as attn_decode, O(used pages) memory.
     Windowing is mask-only here; page reclamation behind an SWA window is
@@ -224,7 +233,7 @@ def attn_decode_paged(p, pool: kvs.PagedKV, table, x, cur_pos, *,
     scale = (d_head ** -0.5) if scale is None else scale
     q, k, v = _qkv(p, x, n_heads, n_kv, d_head, cur_pos[:, None], theta,
                    plan=plan, rope_table=rope_table)
-    pool, o = decode_attend_paged(pool, table, q, k, v, cur_pos,
+    pool, o = decode_attend_paged(pool, layer, table, q, k, v, cur_pos,
                                   window=window, cap=cap, scale=scale,
                                   plan=plan)
     return pool, dense(_merge_heads(o.astype(COMPUTE_DTYPE)), p["wo"],

@@ -194,12 +194,13 @@ def init_stack_state(cfg: ArchConfig, batch: int, slots_full: int,
 
 
 def block_decode(cfg: ArchConfig, p: Dict, st: Dict, x, cur_pos, window,
-                 page_table=None, plan=None, rope=None):
+                 page_table=None, plan=None, rope=None, layer=None):
     """One layer, one token. x [B,1,D] -> (state', x', pairs computed
-    here or None).  ``plan`` (shard.ShardingPlan) threads through to
-    every projection so compressed FC runs shard-local tensor-parallel;
-    None = the replicated single-device path, byte-identical to
-    before."""
+    here or None).  Paged (``page_table`` given): ``st["kv"]`` is the
+    whole stacked pool, written at ``layer`` (see :func:`scan_layers`).
+    ``plan`` (shard.ShardingPlan) threads through to every projection so
+    compressed FC runs shard-local tensor-parallel; None = the
+    replicated single-device path, byte-identical to before."""
     nrm = _norm(cfg)
     if cfg.family == "rwkv6":
         tm_st = {"prev": st["tm_prev"], "S": st["S"]}
@@ -213,8 +214,9 @@ def block_decode(cfg: ArchConfig, p: Dict, st: Dict, x, cur_pos, window,
                 "S": tm_st["S"]}, x + h, None
 
     if page_table is not None:
-        cache, h = attn.attn_decode_paged(p["attn"], st["kv"], page_table,
-                                          nrm(x, p["ln1"]), cur_pos,
+        cache, h = attn.attn_decode_paged(p["attn"], st["kv"], layer,
+                                          page_table, nrm(x, p["ln1"]),
+                                          cur_pos,
                                           window=window, plan=plan,
                                           rope_table=rope,
                                           **_attn_kwargs(cfg))
@@ -239,18 +241,53 @@ def block_decode(cfg: ArchConfig, p: Dict, st: Dict, x, cur_pos, window,
     return new_st, x + h, pairs
 
 
+def scan_layers(cfg: ArchConfig, block, stacked: Dict, states, x,
+                paged: bool, unroll: bool = False):
+    """Scan ``block(p, st, x, lx, layer) -> (st', x', pairs)`` over the
+    layers -> (states', x', per-layer pairs [L] or None).
+
+    Params, ``layer_xs`` and every state leaf are scanned, except a
+    paged pool: the stacked ``states["kv"]`` ([L, n_pages, ...]) rides in
+    the carry beside ``x``, and each layer is handed it whole in
+    ``st["kv"]`` with its index ``layer``, writes its pages
+    (``kvstore.update_layer``) and hands it on.  Scanned in and out
+    instead, XLA would slice each layer out and stack a second pool."""
+    unroll = cfg.n_layers if unroll else 1
+    if not paged:
+        def body(xc, inp):
+            p, st, lx = inp
+            new_st, xo, pairs = block(p, st, xc, lx, None)
+            return xo, (new_st, pairs)
+
+        x, (new_states, pairs) = jax.lax.scan(
+            body, x, (stacked, states, layer_xs(cfg)), unroll=unroll)
+        return new_states, x, pairs
+
+    states = dict(states)
+    pool = states.pop("kv")
+
+    def body(carry, inp):
+        xc, pool = carry
+        p, st, lx, layer = inp
+        new_st, xo, pairs = block(p, {**st, "kv": pool}, xc, lx, layer)
+        new_st = dict(new_st)
+        pool = new_st.pop("kv")
+        return (xo, pool), (new_st, pairs)
+
+    (x, pool), (new_states, pairs) = jax.lax.scan(
+        body, (x, pool),
+        (stacked, states, layer_xs(cfg),
+         jnp.arange(cfg.n_layers, dtype=jnp.int32)), unroll=unroll)
+    return {**new_states, "kv": pool}, x, pairs
+
+
 def stack_decode(cfg: ArchConfig, stacked: Dict, states, x, cur_pos,
                  unroll: bool = False, page_table=None, plan=None):
     """-> (states', x', per-layer pairs computed here [L] or None)."""
-    def body(xc, inp):
-        p, st, lx = inp
-        new_st, xo, pairs = block_decode(cfg, p, st, xc, cur_pos,
-                                         lx["window"],
-                                         page_table=page_table, plan=plan,
-                                         rope=lx["rope"])
-        return xo, (new_st, pairs)
+    def block(p, st, xc, lx, layer):
+        return block_decode(cfg, p, st, xc, cur_pos, lx["window"],
+                            page_table=page_table, plan=plan,
+                            rope=lx["rope"], layer=layer)
 
-    x, (new_states, pairs) = jax.lax.scan(
-        body, x, (stacked, states, layer_xs(cfg)),
-        unroll=cfg.n_layers if unroll else 1)
-    return new_states, x, pairs
+    return scan_layers(cfg, block, stacked, states, x,
+                       paged=page_table is not None, unroll=unroll)

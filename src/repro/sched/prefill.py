@@ -17,9 +17,10 @@ position is sampled, so only its hidden state is unembedded: the step
 returns one [Vpad] row per slot, as the decode step does.
 
 Within-chunk causality needs no extra machinery: all C tokens' K/V are
-written (in ONE vectorized scatter, `kvstore.update_chunk` — same
-two-speed int8 semantics as decode, at chunk granularity) *before* the
-chunk attends, and the page-table index IS the absolute position, so the
+written (`kvstore.update_layer`: the pages they reach, whole, into the
+stacked pool the layer scan carries; int8 pools keep
+`kvstore.update_chunk`'s two-speed semantics) *before* the chunk
+attends, and the page-table index IS the absolute position, so the
 multi-query chunk mask sees in-chunk keys exactly like history.  The
 attention itself dispatches through `kvstore.paged_attention_chunk`
 (tuned Pallas chunk kernel or the XLA gather reference), shard-local
@@ -42,7 +43,7 @@ from repro.configs.base import ArchConfig
 from repro.models import attention as attn
 from repro.models.layers import COMPUTE_DTYPE, embed
 from repro.models.model import serve_logits
-from repro.models.transformer import _norm, ffn, layer_xs
+from repro.models.transformer import _norm, ffn, scan_layers
 
 
 def supports_chunked_prefill(cfg: ArchConfig) -> bool:
@@ -53,30 +54,32 @@ def supports_chunked_prefill(cfg: ArchConfig) -> bool:
 
 
 def _block_prefill(cfg: ArchConfig, p: Dict, st: Dict, x, positions,
-                   valid, window, table, plan=None, rope=None):
-    """One layer over a [B, C, D] chunk: write C tokens' K/V into pages,
-    then attend all C queries over the (now-updated) page table.
-    Returns (state', x', pairs computed here or None)."""
+                   valid, window, table, layer, plan=None, rope=None):
+    """One layer over a [B, C, D] chunk: write C tokens' K/V into the
+    pages of layer ``layer`` of the stacked pool ``st["kv"]``, then
+    attend all C queries over the (now-updated) page table.  Returns
+    (state', x', pairs computed here or None)."""
     nrm = _norm(cfg)
     scale = (cfg.head_dim ** -0.5) if cfg.attn_scale is None \
         else cfg.attn_scale
     q, k, v = attn._qkv(p["attn"], nrm(x, p["ln1"]), cfg.n_heads,
                         cfg.n_kv, cfg.head_dim, positions, cfg.rope_theta,
                         plan=plan, rope_table=rope)
-    pool = st["kv"]
-    pool = kvs.update_chunk(pool, table,
-                            k.astype(jnp.float32), v.astype(jnp.float32),
-                            positions, valid=valid)
-    if plan is not None and plan.tp > 1:
-        from repro.shard import paged_attention_chunk_sharded
-        o = paged_attention_chunk_sharded(
-            plan, q, pool, table, positions,
-            jnp.asarray(window, jnp.int32),
-            scale=scale, cap=cfg.attn_softcap)
-    else:
-        o = kvs.paged_attention_chunk(q, pool, table, positions,
-                                      jnp.asarray(window, jnp.int32),
-                                      scale=scale, cap=cfg.attn_softcap)
+    def attend(src, at):
+        if plan is not None and plan.tp > 1:
+            from repro.shard import paged_attention_chunk_sharded
+            return paged_attention_chunk_sharded(
+                plan, q, src if at is None else kvs.layer_view(src, at),
+                table, positions, jnp.asarray(window, jnp.int32),
+                scale=scale, cap=cfg.attn_softcap)
+        return kvs.paged_attention_chunk(q, src, table, positions,
+                                         jnp.asarray(window, jnp.int32),
+                                         scale=scale, cap=cfg.attn_softcap,
+                                         layer=at)
+
+    pool, o = kvs.update_layer(st["kv"], layer, table,
+                               k.astype(jnp.float32), v.astype(jnp.float32),
+                               positions, valid, attend)
     h = attn.dense(attn._merge_heads(o.astype(COMPUTE_DTYPE)),
                    p["attn"]["wo"], plan=plan)
     new_st = dict(st)
@@ -92,16 +95,12 @@ def _block_prefill(cfg: ArchConfig, p: Dict, st: Dict, x, positions,
 
 def _stack_prefill(cfg: ArchConfig, stacked: Dict, states, x, positions,
                    valid, table, plan=None):
-    def body(xc, inp):
-        p, st, lx = inp
-        new_st, xo, pairs = _block_prefill(cfg, p, st, xc, positions,
-                                           valid, lx["window"], table,
-                                           plan=plan, rope=lx["rope"])
-        return xo, (new_st, pairs)
+    def block(p, st, xc, lx, layer):
+        return _block_prefill(cfg, p, st, xc, positions, valid,
+                              lx["window"], table, layer, plan=plan,
+                              rope=lx["rope"])
 
-    x, (new_states, pairs) = jax.lax.scan(
-        body, x, (stacked, states, layer_xs(cfg)))
-    return new_states, x, pairs
+    return scan_layers(cfg, block, stacked, states, x, paged=True)
 
 
 def prefill_hidden(cfg: ArchConfig, params: Dict, state: Dict,

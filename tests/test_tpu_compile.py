@@ -1,4 +1,5 @@
-"""Ahead-of-time compiles of the serving path's Pallas kernels for a TPU v5e.
+"""Ahead-of-time compiles of the serving path's Pallas kernels, and of its
+two serving steps, for a TPU v5e.
 
 Each test lowers one kernel natively (``interpret=False``) at
 qwen1.5-0.5b widths (d_model 1024, d_ff 2816, 16 heads of 64, 16-token
@@ -11,7 +12,10 @@ The topology is described inside a fixture, never at import time: only
 one process may load the TPU library, and every test worker imports
 this file.
 """
+import dataclasses
+import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -162,3 +166,73 @@ def test_expert_grouped_matmul_compiles(one_chip):
                    p, jax.ShapeDtypeStruct((128, 16, 2304), jnp.bfloat16),
                    jax.ShapeDtypeStruct((128, 16), jnp.bool_))
     assert "ragged-dot" in hlo and "tpu_custom_call" in hlo
+
+
+def _pool_ops(hlo: str, pool_shape) -> dict:
+    """Instructions that copy, transpose or slice out the stacked pool
+    ``pool_shape`` [L, n_pages, Hkv, ps, Dh] or one of its layers (also
+    with pages and slots merged), from the optimized HLO, by the shape
+    they produce: ``{"layer": [...], "stack": [...]}``."""
+    _, p, h, ps, dh = pool_shape
+    layer = {f"{p},{h},{ps},{dh}", f"1,{p},{h},{ps},{dh}",
+             f"{p * ps},{h},{dh}"}
+    stacked = ",".join(map(str, pool_shape))
+    found = {"layer": [], "stack": []}
+    for name, shape, op in re.findall(
+            r"%(\S+) = \w+\[([\d,]*)\]\S* ([\w-]+)\(", hlo):
+        if shape in layer and op not in ("parameter", "bitcast"):
+            found["layer"].append(name)
+        elif shape == stacked and op in ("copy", "transpose", "copy-start",
+                                         "copy-done"):
+            found["stack"].append(name)
+    return found
+
+
+@pytest.mark.parametrize("kind", ["decode", "chunked"])
+@pytest.mark.parametrize("arch,slots,pages", [
+    ("qwen1.5-0.5b", 32, 3201), ("mellum2-12b-ep4", 128, 12801)])
+def test_serving_steps_write_the_pool_in_place(one_chip, arch, slots,
+                                               pages, kind):
+    """Both serving steps at a benchmark cell's widths, slots and pool
+    (two of its layers), the state donated, compile for the chip without
+    copying the stacked pool.  Mellum's 128-wide head is held pages
+    major, so no layer's pool is copied, transposed or sliced out either.
+    Qwen's 64-wide head is held pages minor (the order that pads least),
+    where no page can be gathered or scattered: each layer is sliced out,
+    laid out pages major, written, read and put back."""
+    from repro.configs import get
+    from repro.models import model as M
+    from repro.models import moe
+    from repro.sched.prefill import prefill_step
+    cfg = dataclasses.replace(get(arch), n_layers=2)
+
+    def params():
+        p = M.init_params(cfg, jax.random.PRNGKey(0))
+        return moe.serving_params(p) if cfg.moe else p
+
+    def placed(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=one_chip), tree)
+
+    state = jax.eval_shape(lambda: M.init_decode_state(
+        cfg, slots, 2560, kv_cache="paged", page_size=PAGE,
+        kv_pool_pages=pages, kv_dtype="bf16"))
+    if kind == "decode":
+        fn = functools.partial(M.decode_step, cfg)
+        args = (jax.ShapeDtypeStruct((slots,), jnp.int32),)
+    else:
+        fn = functools.partial(prefill_step, cfg)
+        args = (jax.ShapeDtypeStruct((slots, CHUNK), jnp.int32),
+                jax.ShapeDtypeStruct((slots,), jnp.int32))
+    hlo = jax.jit(fn, donate_argnums=(1,)).lower(
+        placed(jax.eval_shape(params)), placed(state),
+        *placed(args)).compile().as_text()
+    pool_shape = state["layers"]["kv"].k_pages.shape
+    found = _pool_ops(hlo, pool_shape)
+    assert found["stack"] == []
+    if cfg.head_dim % 128 == 0:
+        assert found["layer"] == []
+    # attention reads the gathered pages as bf16: no step writes them out
+    # again in f32 (the decode step did, at 1.34 s of a traced window)
+    gathered = ",".join(map(str, (slots * 2560 // PAGE,) + pool_shape[2:]))
+    assert not re.findall(rf"= f32\[{gathered}\]\S* convert\(", hlo)
